@@ -171,6 +171,17 @@ def get_config(name: str) -> ModelConfig:
     return _REGISTRY[name]
 
 
+def one_chip_config() -> ModelConfig:
+    """yi-9b at its published widths with its depth cut to 24 of 48 layers.
+
+    Every width stays as published (d_model 4096, 32 query heads over 4 KV
+    heads, head_dim 128, d_ff 11008, vocab 64000, bf16).  The whole model
+    is ~17.7 GB of bf16 weights; 24 layers are ~9.4 GB, which leaves room
+    for an 8-slot, 2048-token paged KV pool on one 16 GB v5e chip.
+    """
+    return replace(get_config("yi-9b"), num_layers=24)
+
+
 def list_configs() -> Tuple[str, ...]:
     _ensure_loaded()
     return tuple(sorted(_REGISTRY))

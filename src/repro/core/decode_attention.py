@@ -19,7 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.kernels import ops as kops
 from repro.kernels import ref
 
@@ -74,7 +73,7 @@ def decode_attention(q, k_cache, v_cache, kpos, cur_pos, *, window: Optional[int
                                         window=window, scale=scale)
         return _combine(acc, l, m, s_axes).astype(q_l.dtype)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=plan.mesh,
         in_specs=(P(b_axes), P(b_axes, s_axes), P(b_axes, s_axes), kpos_spec,
                   cur_spec),
@@ -164,7 +163,7 @@ def mla_decode_attention(q_nope, q_rope, ckv, krope, kpos, cur_pos, wk_b, *,
             cur if per_slot else cur[0], scale=scale)
         return _combine(acc, l, m, s_axes)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=plan.mesh,
         in_specs=(P(b_axes), P(b_axes), P(b_axes, s_axes), P(b_axes, s_axes),
                   kpos_spec, cur_spec),

@@ -20,7 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.config import ModelConfig
 from repro.kernels import ops as kops
 from repro.models.layers import KeyGen, dense_init
@@ -88,18 +87,18 @@ def embed_lookup(table, tokens, plan, seq_sharded=None):
             return jax.lax.psum_scatter(rows, tp, scatter_dimension=1,
                                         tiled=True)
 
-        fn = shard_map(local, mesh=plan.mesh,
-                       in_specs=(table_spec, P(b_axes, tp)),
-                       out_specs=P(b_axes, tp), check_vma=False)
+        fn = jax.shard_map(local, mesh=plan.mesh,
+                           in_specs=(table_spec, P(b_axes, tp)),
+                           out_specs=P(b_axes, tp), check_vma=False)
         return fn(table, tokens)
 
     def local(table_l, tokens_l):
         rows = gather_local(table_l, tokens_l)
         return jax.lax.psum(rows, tp)          # activation rows, not the table
 
-    fn = shard_map(local, mesh=plan.mesh,
-                   in_specs=(table_spec, P(b_axes)),
-                   out_specs=P(b_axes), check_vma=False)
+    fn = jax.shard_map(local, mesh=plan.mesh,
+                       in_specs=(table_spec, P(b_axes)),
+                       out_specs=P(b_axes), check_vma=False)
     return fn(table, tokens)
 
 
@@ -207,13 +206,13 @@ def sharded_xent(x, w_head, labels, plan, cfg: ModelConfig,
             i = jax.lax.axis_index(tp)
             return jax.lax.dynamic_slice_in_dim(losses, i * s_loc, s_loc, axis=1)
 
-        fn = shard_map(local_seq, mesh=plan.mesh,
-                       in_specs=(w_spec, P(b_axes, tp), P(b_axes, tp)),
-                       out_specs=P(b_axes, tp), check_vma=False)
+        fn = jax.shard_map(local_seq, mesh=plan.mesh,
+                           in_specs=(w_spec, P(b_axes, tp), P(b_axes, tp)),
+                           out_specs=P(b_axes, tp), check_vma=False)
         return fn(w_head, x, labels)
-    fn = shard_map(local, mesh=plan.mesh,
-                   in_specs=(w_spec, P(b_axes), P(b_axes)),
-                   out_specs=P(b_axes), check_vma=False)
+    fn = jax.shard_map(local, mesh=plan.mesh,
+                       in_specs=(w_spec, P(b_axes), P(b_axes)),
+                       out_specs=P(b_axes), check_vma=False)
     return fn(w_head, x, labels)
 
 
@@ -239,9 +238,9 @@ def sharded_logits_last(x_last, w_head, plan, cfg: ModelConfig):
                             preferred_element_type=jnp.float32)
         return logits
 
-    fn = shard_map(local, mesh=plan.mesh,
-                   in_specs=(w_spec, P(b_axes)),
-                   out_specs=P(b_axes, tp), check_vma=False)
+    fn = jax.shard_map(local, mesh=plan.mesh,
+                       in_specs=(w_spec, P(b_axes)),
+                       out_specs=P(b_axes, tp), check_vma=False)
     logits = fn(w_head, x_last)
     v_pad = w_head.shape[0]
     mask = jnp.arange(v_pad) < cfg.vocab_size
@@ -277,7 +276,7 @@ def greedy_sample(x_last, w_head, plan, cfg: ModelConfig):
         win = jnp.where(val == best, idx, 0)
         return jax.lax.pmax(win, tp).astype(jnp.int32)
 
-    fn = shard_map(local, mesh=plan.mesh,
-                   in_specs=(w_spec, P(b_axes)),
-                   out_specs=P(b_axes), check_vma=False)
+    fn = jax.shard_map(local, mesh=plan.mesh,
+                       in_specs=(w_spec, P(b_axes)),
+                       out_specs=P(b_axes), check_vma=False)
     return fn(w_head, x_last)

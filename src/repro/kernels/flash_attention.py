@@ -24,8 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
-
 NEG_INF = -1e30
 
 
@@ -127,10 +125,11 @@ def flash_attention(q, k, v, *, causal: bool = True, window: Optional[int] = Non
             pltpu.VMEM((qc, 1), jnp.float32),
             pltpu.VMEM((qc, 1), jnp.float32),
         ],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_attention",
     )(q5, k4, v4)
 
     out = out.transpose(0, 3, 1, 2, 4).reshape(B, nq * qc, H, dh)

@@ -25,8 +25,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
-
 
 def _gather_kernel(off_ref, idx_ref, table_ref, w_ref, out_ref, *, ib: int,
                    weighted: bool):
@@ -88,7 +86,7 @@ def isp_gather(table, indices, *, shard_offset=0, weights=None,
         ],
         out_specs=pl.BlockSpec((ib, db), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((idx.shape[0], table.shape[1]), table.dtype),
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(off, idx, table, w)
@@ -165,7 +163,7 @@ def isp_gather_pool(table, indices, segment_ids, num_segments: int, *,
         ],
         out_specs=pl.BlockSpec((num_segments, db), lambda i, j: (0, j)),
         out_shape=jax.ShapeDtypeStruct((num_segments, table.shape[1]), jnp.float32),
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "parallel")),
         interpret=interpret,
     )(off, idx, seg, table, w)

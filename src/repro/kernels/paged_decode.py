@@ -12,6 +12,7 @@ masked attention in one pass:
   scalar prefetch: pages (B, maxp) int32, cur (B,) int32
   q block (G, dh); k/v block (page_size, dh) — one physical page
   scratch: acc (G, dh) f32, m (G, 1), l (G, 1)
+  out: acc (B, Hkv, G, dh), l/m (B, Hkv, G, 1) — reshaped to (B, H)
 
 Unallocated logical pages (table entry -1) are clamped to physical page 0
 for the DMA and masked out by position validity, so the grid shape stays
@@ -30,7 +31,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
 from repro.core import kv_pages
 from repro.kernels import ref
 
@@ -95,8 +95,8 @@ def _kernel(pages_ref, cur_ref, q_ref, k_ref, v_ref,
     @pl.when(ki == nk - 1)
     def _finish():
         acc_ref[0, 0] = acc_s[...]
-        l_ref[0, 0] = l_s[..., 0]
-        m_ref[0, 0] = m_s[..., 0]
+        l_ref[0, 0] = l_s[...]
+        m_ref[0, 0] = m_s[...]
 
 
 def paged_decode_partial(q, kpool, vpool, pages, cur_pos, *,
@@ -140,10 +140,12 @@ def paged_decode_partial(q, kpool, vpool, pages, cur_pos, *,
         out_specs=[
             pl.BlockSpec((1, 1, g, dh),
                          lambda b, h, ki, pages_ref, cur_ref: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, g),
-                         lambda b, h, ki, pages_ref, cur_ref: (b, h, 0)),
-            pl.BlockSpec((1, 1, g),
-                         lambda b, h, ki, pages_ref, cur_ref: (b, h, 0)),
+            # l/m carry a trailing unit axis: the TPU compiler tiles the
+            # last two block dims, and a (g,) tail of (B, Hkv, g) is refused
+            pl.BlockSpec((1, 1, g, 1),
+                         lambda b, h, ki, pages_ref, cur_ref: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, g, 1),
+                         lambda b, h, ki, pages_ref, cur_ref: (b, h, 0, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((g, dh), jnp.float32),
@@ -158,11 +160,12 @@ def paged_decode_partial(q, kpool, vpool, pages, cur_pos, *,
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((B, Hkv, g, dh), jnp.float32),
-            jax.ShapeDtypeStruct((B, Hkv, g), jnp.float32),
-            jax.ShapeDtypeStruct((B, Hkv, g), jnp.float32),
+            jax.ShapeDtypeStruct((B, Hkv, g, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B, Hkv, g, 1), jnp.float32),
         ],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="paged_decode",
     )(pages, cur, q3, k4, v4)
     return (acc.reshape(B, H, dh), l.reshape(B, H), m.reshape(B, H))

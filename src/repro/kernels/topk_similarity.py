@@ -20,8 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
-
 NEG_INF = -1e30
 
 
@@ -113,7 +111,7 @@ def topk_similarity(queries, corpus, k: int, *, q_block: int = 128,
             pltpu.VMEM((qb, k), jnp.float32),
             pltpu.VMEM((qb, k), jnp.int32),
         ],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(qn, cn)

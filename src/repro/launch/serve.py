@@ -63,6 +63,7 @@ from repro.core.cluster import ROUTING_POLICIES
 from repro.data.workload import (ARRIVAL_MODES, DEFAULT_CLASSES,
                                  PriorityClass, WorkloadConfig,
                                  generate_trace, replay_open_loop)
+from repro.launch.compiles import use_persistent_cache
 from repro.models import model as M
 from repro.train.cluster_loop import ClusterEngine
 from repro.train.serve_loop import AdmissionController, ServeEngine
@@ -201,6 +202,7 @@ def main() -> int:
                          "enables the telemetry hub")
     args = ap.parse_args()
 
+    print(f"[serve] compile cache: {use_persistent_cache()}")
     cfg = reduced_config(args.arch) if args.smoke else get_config(args.arch)
     params = M.init_params(cfg, jax.random.PRNGKey(args.seed))
     engine_kw = dict(max_len=args.max_len, num_slots=args.num_slots,

@@ -14,6 +14,7 @@ import argparse
 
 from repro.config import get_config, reduced_config
 from repro.data import DataConfig
+from repro.launch.compiles import use_persistent_cache
 from repro.launch.elastic import FailureInjector
 from repro.train.train_loop import TrainConfig, train
 
@@ -33,6 +34,7 @@ def main() -> int:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    print(f"[train] compile cache: {use_persistent_cache()}")
     cfg = reduced_config(args.arch) if args.smoke else get_config(args.arch)
     data_cfg = DataConfig(seq_len=args.seq_len, global_batch=args.global_batch,
                           vocab_size=cfg.vocab_size, seed=args.seed)

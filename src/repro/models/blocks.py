@@ -17,7 +17,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.config import ModelConfig
 from repro.models import attention as attn_mod
 from repro.models import moe as moe_mod
@@ -102,9 +101,9 @@ def apply_moe(params, x, cfg: ModelConfig, plan, mode: str):
             return y.reshape(x_l.shape)
 
         specs = _moe_specs(plan, plan.fsdp_axis)
-        fn = shard_map(local, mesh=plan.mesh,
-                       in_specs=(specs, P(b_axes, None, None)),
-                       out_specs=P(b_axes, None, None), check_vma=False)
+        fn = jax.shard_map(local, mesh=plan.mesh,
+                           in_specs=(specs, P(b_axes, None, None)),
+                           out_specs=P(b_axes, None, None), check_vma=False)
         y = fn(routed, x)
         aux = jnp.float32(0.0)       # decode: no aux loss needed
     else:
@@ -123,9 +122,9 @@ def apply_moe(params, x, cfg: ModelConfig, plan, mode: str):
             return y.reshape(x_l.shape), aux
 
         specs = _moe_specs(plan, plan.fsdp_axis)
-        fn = shard_map(local, mesh=plan.mesh,
-                       in_specs=(specs, P(b_axes, tp, None)),
-                       out_specs=(P(b_axes, tp, None), P()), check_vma=False)
+        fn = jax.shard_map(local, mesh=plan.mesh,
+                           in_specs=(specs, P(b_axes, tp, None)),
+                           out_specs=(P(b_axes, tp, None), P()), check_vma=False)
         y, aux = fn(routed, x)
 
     if m.num_shared_experts:

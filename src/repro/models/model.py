@@ -42,7 +42,12 @@ def num_groups(cfg: ModelConfig) -> int:
 # ---------------------------------------------------------------------------
 
 
+@functools.partial(jax.jit, static_argnums=0)
 def init_params(cfg: ModelConfig, key) -> Dict[str, Any]:
+    """Random params drawn from ``key``, as one jitted program: each float32
+    draw fuses into its cast to the model dtype, so no whole float32 weight
+    is ever held on the device (eager init would, once per weight, at full
+    model widths)."""
     dtype = _dtype(cfg)
     kg = KeyGen(key)
     params: Dict[str, Any] = {"embed": {"table": emb.embed_params(cfg, kg, dtype)}}

@@ -31,8 +31,10 @@ Mechanics:
     in-flight requests from their prompts on the surviving drives (greedy
     decode is deterministic, so a restarted request still yields identical
     tokens) and keeps the dead drive's stats merged into the cluster view;
-  * replicas share one set of jitted callables (``jit_donor``), so an
-    N-drive cluster costs one XLA compile, not N;
+  * drive d sits on device d of the host, round robin, with its own copy
+    of the params; replicas share one set of jitted callables
+    (``jit_donor``), so an N-drive cluster costs one XLA compile per
+    device, not one per drive;
   * a cluster-wide pull scheduler (``core.scheduler.ClusterAdmission``)
     learns every drive's service rate from per-tick observations
     (``ServeEngine.last_tick``); ``rate_aware`` routing consumes the live
@@ -72,6 +74,7 @@ from collections import deque
 from dataclasses import dataclass, field
 from typing import Deque, Dict, List, Optional, Sequence
 
+import jax
 import jax.numpy as jnp
 
 from repro.config import ModelConfig
@@ -220,13 +223,20 @@ class ClusterEngine:
             # any worker thread exists (drive 0 pays once; the rest
             # share its cache via the donor chain below)
             engine_kw["prewarm"] = True
+        # drive d holds its own copy of the params (each CSD holds its own
+        # model) on device d of the host, round robin: one drive per chip on
+        # a four-chip host.  Its engine puts its caches and decode state
+        # beside them.
+        devices = jax.devices()
         for d in range(n_drives):
             donor = jit_donor if jit_donor is not None else \
                 (self.drives[0].engine if self.drives else None)
             kw = dict(engine_kw)
             if admission_factory is not None:
                 kw["admission"] = admission_factory()
-            eng = ServeEngine(cfg, params, jit_donor=donor, **kw)
+            eng = ServeEngine(
+                cfg, jax.device_put(params, devices[d % len(devices)]),
+                jit_donor=donor, **kw)
             eng.tele = self.tele
             eng.tele_track = f"drive{d}"
             eng.tele_requests = False

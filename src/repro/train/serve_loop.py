@@ -17,9 +17,10 @@ Mechanics:
     EOS/max-new/cache-full termination masks and KV writes — and returns a
     single (K, num_slots) token block to the host.  Cache pools are
     donated (in-place on accelerators), and tokens/positions/page-table
-    live as persistent device arrays mutated with ``.at[]`` instead of
-    being re-uploaded per step.  ``k_block=1`` keeps the per-step host
-    loop as the reference the fused path is property-tested against;
+    live as persistent device arrays, uploaded whole from the host's view
+    only when admission, growth or a finish changes them (one fixed shape
+    each, so serving never compiles).  ``k_block=1`` keeps the per-step
+    host loop as the reference the fused path is property-tested against;
   * KV lives in a paged pool by default (``core.kv_pages``): prefill
     allocates ``ceil(len/page_size)`` fixed-size pages per slot, decode
     pre-reserves the pages a whole K-block can touch (a host-side lookup
@@ -364,6 +365,12 @@ class ServeEngine:
             raise ValueError(f"admission_order must be 'fifo' or 'edf', "
                              f"got {admission_order!r}")
         self.cfg = cfg
+        # device: the one device the params sit on.  The caches and decode
+        # state go there too, so every jitted call runs there, because its
+        # committed arguments do.  Params spread over several devices (a
+        # sharded recipe) leave it None: JAX's default device.
+        on = {d for x in jax.tree.leaves(params) for d in x.devices()}
+        self.device = on.pop() if len(on) == 1 else None
         self.params = params
         self.recipe = recipe if recipe is not None else M.LOCAL
         self.max_len = max_len
@@ -396,6 +403,7 @@ class ServeEngine:
             self._prefill = jit_donor._prefill
             self._decode_block = jit_donor._decode_block
             self._prefill_chunk = jit_donor._prefill_chunk
+            self._splice_pages = jit_donor._splice_pages
         else:
             self._decode = jax.jit(
                 lambda p, c, t, pos: M.decode_fn(p, c, t, pos, cfg,
@@ -416,6 +424,8 @@ class ServeEngine:
                 lambda p, c, t, qpos, last: M.prefill_chunk_fn(
                     p, c, t, qpos, last, cfg, self.recipe),
                 donate_argnums=(1,) if donate else ())
+            self._splice_pages = jax.jit(
+                _splice_paged_group, donate_argnums=(0, 1) if donate else ())
         # KV layout: "paged" (default) keeps full-attention KV in fixed-size
         # pages handed out by a free-list allocator — memory and decode
         # reads track live tokens; "strip" is the dense per-slot reference
@@ -433,35 +443,32 @@ class ServeEngine:
         if chunk_prefill and self.kv_layout == "paged" and \
                 all(k in ("attn", "moe") for k in cfg.layer_pattern):
             self.chunk_prefill = max(int(chunk_prefill), 1)
-        if self.kv_layout == "paged":
+        paged = self.kv_layout == "paged"
+        if paged:
             if num_pages is None:
                 num_pages = num_slots * self._maxp        # dense worst case
             self.pager: Optional[PageAllocator] = PageAllocator(
                 num_pages, self.page_size)
             self.page_table = np.full((num_slots, self._maxp), -1, np.int32)
-            self.caches = M.init_caches(cfg, num_slots, max_len, paged=True,
-                                        page_size=self.page_size,
-                                        num_pages=num_pages)
-            # device-resident page table: the single device copy, mutated
-            # with .at[] sets as slots are admitted/grown/finished — never
-            # re-uploaded wholesale (mid-prefill slots keep -1 rows so
-            # decode writes route to the scratch page until splice is done)
-            self._pages_dev = jnp.full((num_slots, self._maxp), -1,
-                                       jnp.int32)
-            self._sync_pages_leaves()
         else:
             self.pager = None
             self.page_table = None
-            self._pages_dev = None
-            self.caches = M.init_caches(cfg, num_slots, max_len, per_slot=True)
-        # per-slot decode state for the fused block: persistent device
-        # arrays mutated with .at[] at admission/finish, round-tripped
-        # through the block — never rebuilt/re-uploaded per step
-        self._tok_dev = jnp.zeros((num_slots,), jnp.int32)
-        self._pos_dev = jnp.zeros((num_slots,), jnp.int32)
-        self._alive_dev = jnp.zeros((num_slots,), bool)
-        self._rem_dev = jnp.zeros((num_slots,), jnp.int32)
+        # built on the drive's device (not staged through the default one),
+        # then committed to it
+        with jax.default_device(self.device):
+            self.caches = self._put(M.init_caches(
+                cfg, num_slots, max_len, per_slot=True, paged=paged,
+                page_size=self.page_size, num_pages=num_pages))
+        # per-slot decode state for the fused block, and the device page
+        # table: persistent device arrays round-tripped through the block,
+        # uploaded whole from the host's view only when admission, page
+        # growth or a finish changes them (_upload_slot_state /
+        # _upload_pages) — never per step
         self.slots = [_Slot(index=i) for i in range(num_slots)]
+        self._upload_slot_state()
+        self._pages_dev = None
+        if paged:
+            self._upload_pages()
         self.queue: Deque[_Request] = deque()
         self.stats = ServeStats()
         self.ledger = self.stats.ledger          # chosen-plan link bytes
@@ -505,8 +512,11 @@ class ServeEngine:
         # replicas SHARE their donor's live set (jit executables are
         # cached per shared callable and eager ones process-wide, so a
         # shape any replica has run is warm for all of them).
-        self._warm_keys: set = set() if jit_donor is None \
-            else jit_donor._warm_keys
+        # A replica on another device compiles its own executables, so it
+        # keeps its own set.
+        self._warm_keys: set = jit_donor._warm_keys \
+            if jit_donor is not None and jit_donor.device == self.device \
+            else set()
         self._tick_compile_s = 0.0
         self.last_tick = TickObservation()
         if prewarm:
@@ -519,44 +529,37 @@ class ServeEngine:
         none (pure window/recurrent/MLA stacks) serves on the strip layout."""
         return any(k in ("attn", "moe") for k in self.cfg.layer_pattern)
 
-    def _sync_pages_leaves(self) -> None:
-        """Point every group's ``pages`` cache leaf at the device page
-        table.  Called only when the table actually changed (admission,
-        block-granular growth, finish) — the per-step full re-push of the
-        host table is gone; ``_pages_dev`` is mutated with .at[] sets."""
+    def _put(self, x):
+        """Place a host value (or pytree) on this drive's device."""
+        return jax.device_put(x, self.device)
+
+    def _upload_pages(self) -> None:
+        """Upload the device page table from the host's and point every
+        group's ``pages`` cache leaf at it.  Mid-prefill slots keep -1 rows
+        there, so decode writes route to the scratch page until their last
+        chunk is spliced.  A fixed-shape upload: it never compiles."""
+        table = self.page_table.copy()
+        table[[s.index for s in self.slots if s.prefilling]] = -1
+        self._pages_dev = self._put(table)
         for g, cache in self.caches.items():
             if isinstance(cache, dict) and "pages" in cache:
                 ng = cache["pages"].shape[0]
                 self.caches[g] = dict(cache, pages=jnp.broadcast_to(
                     self._pages_dev[None], (ng,) + self._pages_dev.shape))
 
-    def _set_pages_rows(self, slot_ids: List[int]) -> None:
-        """Copy the host table's rows for ``slot_ids`` to the device table."""
-        t0 = time.perf_counter()
-        idx = jnp.asarray(slot_ids, jnp.int32)
-        rows = jnp.asarray(self.page_table[np.asarray(slot_ids)])
-        self._pages_dev = self._pages_dev.at[idx].set(rows)
-        self._sync_pages_leaves()
-        # first call per row count: the eager scatter/broadcast executables
-        # compile — attribute that to compile_s, not the serving tick
-        self._serving_time(("set_rows", len(slot_ids)), time.perf_counter() - t0)
-
-    def _sync_slot_dev(self, slots: List[_Slot]) -> None:
-        """Refresh the device-side decode state of ``slots`` (post-prefill /
-        post-finish) with .at[] scatters — the only host→device traffic the
-        fused loop needs between blocks."""
-        t0 = time.perf_counter()
-        idx = jnp.asarray([s.index for s in slots], jnp.int32)
-        self._tok_dev = self._tok_dev.at[idx].set(
-            jnp.asarray([s.cur_token for s in slots], jnp.int32))
-        self._pos_dev = self._pos_dev.at[idx].set(
-            jnp.asarray([s.pos for s in slots], jnp.int32))
-        self._alive_dev = self._alive_dev.at[idx].set(
-            jnp.asarray([s.decoding for s in slots], bool))
-        self._rem_dev = self._rem_dev.at[idx].set(
-            jnp.asarray([max(s.max_new - len(s.out), 0) for s in slots],
-                        jnp.int32))
-        self._serving_time(("sync_slot", len(slots)), time.perf_counter() - t0)
+    def _upload_slot_state(self) -> None:
+        """Upload the fused block's per-slot decode state from the host's
+        slots (after a prefill, a finish or a cancel).  Between blocks the
+        host's replay matches the device's state for every live slot;
+        dead slots only need ``alive`` false."""
+        self._tok_dev = self._put(np.asarray(
+            [s.cur_token for s in self.slots], np.int32))
+        self._pos_dev = self._put(np.asarray(
+            [s.pos for s in self.slots], np.int32))
+        self._alive_dev = self._put(np.asarray(
+            [s.decoding for s in self.slots], bool))
+        self._rem_dev = self._put(np.asarray(
+            [max(s.max_new - len(s.out), 0) for s in self.slots], np.int32))
 
     def _reservation(self, prompt_len: int, max_new: int) -> int:
         """Pages a request can ever need: prompt + generated tokens, capped
@@ -630,9 +633,11 @@ class ServeEngine:
     def prewarm(self) -> float:
         """Compile every jitted entry point this engine can hit before the
         first request: the decode block (or the K=1 step), every prefill
-        bucket shape up to ``max_len`` (the batch dimension is fixed at
-        ``num_slots``, so each bucket length is exactly one compile) and the
-        chunk-prefill shape.  First-request latency and ``decode_s`` then
+        bucket shape a one-shot prompt can take — up to ``max_len``, or up
+        to ``chunk_prefill`` where longer prompts are chunked — with its
+        paged splice (the batch dimension is fixed at ``num_slots``, so
+        each bucket length is exactly one compile), and the chunk-prefill
+        shape.  First-request latency and ``decode_s`` then
         measure serving, not compilation; the compile time is reported
         separately as ``ServeStats.compile_s``.  Returns total compile_s.
         """
@@ -654,32 +659,39 @@ class ServeEngine:
             self._warm_keys.add(("decode",))
             nxt, caches = self._decode(
                 self.params, self.caches,
-                jnp.zeros((self.num_slots, 1), jnp.int32),
-                jnp.zeros((self.num_slots,), jnp.int32))
+                self._put(np.zeros((self.num_slots, 1), np.int32)),
+                self._put(np.zeros((self.num_slots,), np.int32)))
             jax.block_until_ready(nxt)
             self.caches = caches
-        buckets = sorted({self._bucket_len(n)
-                          for n in range(1, self.max_len)})
+        longest = min(self.chunk_prefill or self.max_len, self.max_len - 1)
+        buckets = sorted({self._bucket_len(n) for n in range(1, longest + 1)})
         if len(buckets) <= self.max_len // self.bucket_quantum + 2:
             # bounded bucket set (padding-safe archs); exact-length
             # bucketing (recurrent stacks) would mean max_len compiles —
             # those engines warm lazily per length instead
             for padded in buckets:
-                batch = {"tokens": jnp.zeros((self.num_slots, padded),
-                                             jnp.int32),
-                         "lengths": jnp.ones((self.num_slots,), jnp.int32)}
+                batch = {"tokens": self._put(np.zeros(
+                             (self.num_slots, padded), np.int32)),
+                         "lengths": self._put(np.ones(
+                             (self.num_slots,), np.int32))}
                 self._warm_keys.add(("prefill", padded))
-                jax.block_until_ready(self._prefill(self.params, batch)[0])
+                _, pre = self._prefill(self.params, batch)
+                if self.kv_layout == "paged":
+                    # no real rows: every write lands in the scratch page
+                    self._warm_keys.add(("splice", padded))
+                    self._splice(pre, [], [], padded)
+                jax.block_until_ready(pre)
         if self.chunk_prefill is not None:
             # an all-pad chunk against an empty page row: every write routes
             # to the scratch page.  The pool view is donated, so keep the
             # returned kp/vp leaves (only scratch rows changed).
             self._warm_keys.add(("chunk",))
             view = self._chunk_view(np.full((self._maxp,), -1, np.int32))
-            tokens = jnp.zeros((1, self.chunk_prefill), jnp.int32)
-            qpos = jnp.full((1, self.chunk_prefill), -1, jnp.int32)
+            tokens = self._put(np.zeros((1, self.chunk_prefill), np.int32))
+            qpos = self._put(np.full((1, self.chunk_prefill), -1, np.int32))
             nxt, new_view = self._prefill_chunk(
-                self.params, view, tokens, qpos, jnp.zeros((1,), jnp.int32))
+                self.params, view, tokens, qpos,
+                self._put(np.zeros((1,), np.int32)))
             jax.block_until_ready(nxt)
             for g, cache in new_view.items():
                 self.caches[g] = dict(self.caches[g], kp=cache["kp"],
@@ -753,7 +765,7 @@ class ServeEngine:
                     # the fused block keeps liveness on device; a released
                     # slot must be marked dead there or the next block
                     # would keep decoding into freed (re-allocatable) pages
-                    self._sync_slot_dev([s])
+                    self._upload_slot_state()
                 return wasted
         self.records.pop(rid, None)
         return None
@@ -968,7 +980,7 @@ class ServeEngine:
         if self.kv_layout == "paged" and oneshot:
             # mid-prefill slots keep their device row -1 (decode writes hit
             # the scratch page) until their last chunk is spliced
-            self._set_pages_rows([s.index for s in oneshot])
+            self._upload_pages()
 
         buckets: Dict[int, List[_Slot]] = {}
         for slot in oneshot:
@@ -990,25 +1002,19 @@ class ServeEngine:
             tokens[i, : lengths[i]] = s._prompt
             lens[i] = lengths[i]
         t0 = time.perf_counter()
-        batch = {"tokens": jnp.asarray(tokens),
-                 "lengths": jnp.asarray(lens)}
+        batch = {"tokens": self._put(tokens), "lengths": self._put(lens)}
         nxt, pre_caches = self._prefill(self.params, batch)
-        jax.block_until_ready(nxt)
+        nxt = np.asarray(nxt)
         t1 = time.perf_counter()
-        # prefill jit is keyed by the bucket length; the splice runs eager
-        # gather/scatter executables keyed by the total token count — both
-        # compile lazily on first sight, and that wall time is XLA, not
-        # serving (see _serving_time)
+        # prefill jit is keyed by the bucket length, and so is the paged
+        # splice (its index arrays are padded to the bucket's rows); the
+        # strip splice runs eager executables keyed by the group size too.
+        # Each compiles lazily on first sight, and that wall time is XLA,
+        # not serving (see _serving_time)
         dt = self._serving_time(("prefill", padded), t1 - t0)
-        self.caches = _splice_slots(self.caches, pre_caches,
-                                    [s.index for s in group], lengths,
-                                    self.page_table, self.page_size)
-        # the eager splice executables are shaped by BOTH the gathered src
-        # leaves (padded) and the index arrays (total tokens) — a new
-        # padded length with a previously seen total is still a fresh
-        # compile, so the key needs both
-        splice_key = ("splice", padded, sum(lengths)) \
-            if self.kv_layout == "paged" else ("splice", b, padded)
+        self._splice(pre_caches, [s.index for s in group], lengths, padded)
+        splice_key = ("splice", padded) if self.kv_layout == "paged" \
+            else ("splice", b, padded)
         dt += self._serving_time(splice_key, time.perf_counter() - t1)
         self._account_prefill(sum(lengths))
         self.clock += dt               # first tokens are stamped post-prefill
@@ -1023,7 +1029,32 @@ class ServeEngine:
             # the prefill-sampled token is the first generated token
             self._push_token(s, s.cur_token)
         if self.k_block > 1:
-            self._sync_slot_dev(group)
+            self._upload_slot_state()
+
+    def _splice(self, pre_caches, slot_ids: List[int], lengths: List[int],
+                padded: int) -> None:
+        """Write a prefill bucket's dense caches into the slot pool.
+        Paged groups scatter through one jitted, pool-donating program per
+        bucket length; strip groups keep the dense per-slot splice."""
+        index = None
+        if self.kv_layout == "paged":
+            # the pool's last page (index num_pages) is the scratch page
+            index = self._put(_paged_splice_index(
+                slot_ids, lengths, self.page_table, self.page_size,
+                rows=self.num_slots * padded, scratch=self.pager.num_pages))
+        out = {}
+        for g, dst in self.caches.items():
+            src = pre_caches[g]
+            if isinstance(dst, dict) and "pages" in dst:
+                kp, vp = self._splice_pages(dst["kp"], dst["vp"], src["k"],
+                                            src["v"], *index)
+                out[g] = dict(dst, kp=kp, vp=vp)
+            elif slot_ids:
+                out[g] = _splice_strip_group(dst, src, slot_ids, lengths)
+            else:
+                out[g] = dst
+        jax.block_until_ready(out)
+        self.caches = out
 
     def _chunk_prefill_tick(self) -> None:
         """Advance up to ``chunk_budget`` prefill chunks this tick.
@@ -1056,9 +1087,9 @@ class ServeEngine:
         view = self._chunk_view(self.page_table[slot.index])
         t0 = time.perf_counter()
         nxt, new_view = self._prefill_chunk(
-            self.params, view, jnp.asarray(tokens), jnp.asarray(qpos),
-            jnp.asarray([real - 1], jnp.int32))
-        jax.block_until_ready(nxt)
+            self.params, view, self._put(tokens), self._put(qpos),
+            self._put(np.asarray([real - 1], np.int32)))
+        nxt = np.asarray(nxt)
         dt = self._serving_time(("chunk",), time.perf_counter() - t0)
         self.clock += dt
         if self.tele.enabled:
@@ -1076,16 +1107,16 @@ class ServeEngine:
             slot.prefilling = False
             slot.cur_token = int(nxt[0])
             del slot._prompt
-            self._set_pages_rows([slot.index])
+            self._upload_pages()
             self._push_token(slot, slot.cur_token)
             if self.k_block > 1:
-                self._sync_slot_dev([slot])
+                self._upload_slot_state()
 
     def _chunk_view(self, table_row: np.ndarray):
         """B=1 view of the paged caches for one slot: the shared kp/vp
         pools under the slot's own page-table row — the chunk splices into
         the pool without the other slots' batch dimension in the program."""
-        row = jnp.asarray(table_row[None])            # (1, maxp)
+        row = self._put(table_row[None])              # (1, maxp)
         view = {}
         for g, cache in self.caches.items():
             ng = cache["pages"].shape[0]
@@ -1109,8 +1140,8 @@ class ServeEngine:
             self._grow_pages(1)
         t0 = time.perf_counter()
         nxt, self.caches = self._decode(self.params, self.caches,
-                                        jnp.asarray(tokens),
-                                        jnp.asarray(positions))
+                                        self._put(tokens),
+                                        self._put(positions))
         nxt = np.asarray(nxt)
         dt = self._serving_time(("decode",), time.perf_counter() - t0)
         self.stats.decode_s += dt
@@ -1222,7 +1253,6 @@ class ServeEngine:
         never reserves past a slot's own max-new budget.  Admission reserved
         the worst case, so this never exhausts the pool
         (``_reservable_pages`` accounts for the unallocated tail)."""
-        t0 = time.perf_counter()
         grew = False
         ps = self.page_size
         for s in self.slots:
@@ -1232,14 +1262,10 @@ class ServeEngine:
             last = min(s.pos + e - 1, self.max_len - 1)
             for lp in range(s.pos // ps, last // ps + 1):
                 if self.page_table[s.index, lp] < 0:
-                    page = self.pager.alloc(1)[0]
-                    self.page_table[s.index, lp] = page
-                    self._pages_dev = self._pages_dev.at[s.index, lp].set(
-                        page)
+                    self.page_table[s.index, lp] = self.pager.alloc(1)[0]
                     grew = True
         if grew:
-            self._sync_pages_leaves()
-            self._serving_time(("grow_pages",), time.perf_counter() - t0)
+            self._upload_pages()
 
     def _finish(self, slot: _Slot) -> None:
         res = GenResult(tokens=slot.out, rid=slot.rid, tier=slot.tier,
@@ -1280,7 +1306,7 @@ class ServeEngine:
                 self.pager.free(live)
             self.page_table[slot.index, :] = -1
             slot.reserved_pages = 0
-            self._set_pages_rows([slot.index])
+            self._upload_pages()
 
     # -- transfer accounting -------------------------------------------------
 
@@ -1340,52 +1366,38 @@ def collect_results(engine, rids: List[int]) -> List[GenResult]:
     return [by_rid[r] for r in rids]
 
 
-def _splice_slots(pool, pre, slot_ids: List[int], lengths: List[int],
-                  page_table=None, page_size: int = 0):
-    """Scatter a bucket's prefill caches into the per-slot pool.
+def _paged_splice_index(slot_ids: List[int], lengths: List[int],
+                        page_table, page_size: int, rows: int, scratch: int):
+    """Index arrays (src batch, src position, dst page, dst offset) that
+    scatter a prefill bucket's real prompt rows into their pages.
 
-    Dispatches per layer group: paged groups (kp/vp pools + page table)
-    scatter prompt rows into their allocated pages; strip groups keep the
-    dense per-slot tree splice.
+    Only the first ``lengths[i]`` rows of each sequence are real — pad rows
+    are never scattered, so the pool only ever holds live tokens.  The
+    arrays are padded to ``rows`` entries (the bucket's whole batch), and
+    the pad entries copy source row (0, 0) to the ``scratch`` page, which
+    is never read back: each bucket length is one program shape.
     """
-    out = {}
-    for gname, dst in pool.items():
-        src = pre[gname]
-        if isinstance(dst, dict) and "pages" in dst:
-            out[gname] = _splice_paged_group(dst, src, slot_ids, lengths,
-                                             page_table, page_size)
-        else:
-            out[gname] = _splice_strip_group(dst, src, slot_ids, lengths)
-    return out
-
-
-def _splice_paged_group(dst, src, slot_ids: List[int], lengths: List[int],
-                        page_table, page_size: int):
-    """Scatter prefill rows into the paged pool.
-
-    ``src`` leaves are dense (ng, b, padded, ...); only the first
-    ``lengths[i]`` rows of each sequence are real — pad rows are never
-    scattered, so the pool only ever holds live tokens (positions past the
-    current one are invisible to the kernel until their decode step
-    overwrites them).
-    """
-    src_b, src_pos, dst_page, dst_off = [], [], [], []
-    for i, (sid, n) in enumerate(zip(slot_ids, lengths)):
+    sb = np.zeros(rows, np.int32)
+    sp = np.zeros(rows, np.int32)
+    dp = np.full(rows, scratch, np.int32)
+    do = np.zeros(rows, np.int32)
+    i = 0
+    for b, (sid, n) in enumerate(zip(slot_ids, lengths)):
         p = np.arange(n)
-        src_b.append(np.full(n, i))
-        src_pos.append(p)
-        dst_page.append(page_table[sid, p // page_size])
-        dst_off.append(p % page_size)
-    sb, sp = np.concatenate(src_b), np.concatenate(src_pos)
-    pages_np = np.concatenate(dst_page)
-    assert (pages_np >= 0).all(), "prefill splice into unallocated page"
-    dp = jnp.asarray(pages_np)
-    do = jnp.asarray(np.concatenate(dst_off))
-    return dict(
-        dst,
-        kp=dst["kp"].at[:, dp, do].set(src["k"][:, sb, sp].astype(dst["kp"].dtype)),
-        vp=dst["vp"].at[:, dp, do].set(src["v"][:, sb, sp].astype(dst["vp"].dtype)),
-    )
+        sb[i: i + n] = b
+        sp[i: i + n] = p
+        dp[i: i + n] = page_table[sid, p // page_size]
+        do[i: i + n] = p % page_size
+        i += n
+    assert (dp >= 0).all(), "prefill splice into unallocated page"
+    return sb, sp, dp, do
+
+
+def _splice_paged_group(kp, vp, k, v, sb, sp, dp, do):
+    """Scatter prefill rows ``k/v[:, sb, sp]`` (dense (ng, b, padded, ...))
+    into pool rows ``kp/vp[:, dp, do]`` — jitted with the pools donated."""
+    return (kp.at[:, dp, do].set(k[:, sb, sp].astype(kp.dtype)),
+            vp.at[:, dp, do].set(v[:, sb, sp].astype(vp.dtype)))
 
 
 def _splice_strip_group(pool, pre, slot_ids: List[int], lengths: List[int]):

@@ -1,7 +1,7 @@
 """Device-resident fused K-block decode loop + chunked prefill: early-exit
 semantics, same-tick page release, chunked-vs-one-shot prefill equivalence
-(caches and sampled tokens), jit pre-warm accounting, and the bench guard's
-payload invariants."""
+(caches and sampled tokens), jit pre-warm accounting, a compile-free served
+window after it, and the bench guard's payload invariants."""
 import dataclasses
 
 import jax
@@ -189,3 +189,21 @@ def test_prewarm_reports_compile_time_and_stays_identical(cfg, params, rng):
     assert cold.stats.compile_s > 0
     assert cold.stats.decode_s < cold.stats.compile_s
     assert warm.stats.decode_s < warm.stats.compile_s
+
+
+def test_prewarmed_engine_compiles_nothing_while_serving(cfg, params, rng):
+    """After prewarm, a served window — one-shot and chunked prompts, more
+    requests than slots, refills, page growth and frees — lowers no
+    program at all: every shape it runs was compiled before it began."""
+    from repro.launch.compiles import count_compiles
+    engine = make_engine(cfg, params, num_slots=3, page_size=8, k_block=4,
+                         chunk_prefill=16, prewarm=True)
+    lengths = (3, 16, 17, 40, 9, 25, 60)
+    with count_compiles() as lowered:
+        for n in lengths:
+            engine.submit(rng.integers(0, cfg.vocab_size, n).tolist(),
+                          max_new=3)
+        results = engine.run_until_complete()
+    assert lowered == []
+    assert [len(r.tokens) for r in results] == [3] * len(lengths)
+    engine.pager.check_balanced()

@@ -23,7 +23,8 @@ def _run(body: str, devices: int = 8, timeout: int = 900):
         from repro.config import reduced_config, ShapeConfig
         from repro.models import model as M
         from repro.sharding import make_plan, make_recipe
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 3)
         """) + textwrap.dedent(body)
     env = dict(os.environ)
     env["PYTHONPATH"] = str(ROOT / "src")
@@ -125,7 +126,7 @@ def test_dryrun_compiles_small_mesh_all_archs():
 @pytest.mark.slow
 def test_compressed_psum_matches_psum():
     out = _run("""
-        from repro.compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.optim import compressed_psum
         rng = np.random.default_rng(0)
@@ -142,6 +143,43 @@ def test_compressed_psum_matches_psum():
         err = float(jnp.abs(got - want).max())
         amax = float(jnp.abs(x).max())
         assert err <= 2 * 2 * amax / 127.0 + 1e-6, err
+        print("OK")
+    """)
+    assert "OK" in out
+
+
+@pytest.mark.slow
+def test_cluster_drives_on_own_devices_match_one_drive():
+    """Four drives behind data_local routing on an eight-device host: each
+    has its own params copy, caches and decode state on its own device
+    (drive d on device d), and they emit the tokens one drive emits for the
+    same requests."""
+    out = _run("""
+        from repro.train.cluster_loop import ClusterEngine
+        from repro.train.serve_loop import ServeEngine
+        cfg = replace(reduced_config("yi-9b"), dtype="float32")
+        params = M.init_params(cfg, jax.random.PRNGKey(0))
+        rng = np.random.default_rng(0)
+        reqs = [rng.integers(0, cfg.vocab_size, int(n)).tolist()
+                for n in rng.integers(4, 60, 12)]
+        kw = dict(max_len=128, num_slots=4, k_block=4, chunk_prefill=32,
+                  prewarm=True)
+        devices = jax.devices()[:4]
+        cluster = ClusterEngine(cfg, params, n_drives=4, routing="data_local",
+                                **kw)
+        for i, p in enumerate(reqs):
+            cluster.submit(p, max_new=6, shard_id=i % 4)
+        got = cluster.run_until_complete()
+        for d in cluster.drives:
+            e = d.engine
+            on = {x.device for x in jax.tree.leaves(
+                (e.params, e.caches, e._tok_dev, e._pages_dev))}
+            assert on == {devices[d.drive_id]}, (d.drive_id, on)
+            e.pager.check_balanced()
+        assert sorted({r.drive for r in got}) == [0, 1, 2, 3]
+        one = ServeEngine(cfg, params, **kw)
+        want = one.generate(reqs, max_new=6)
+        assert [r.tokens for r in got] == [r.tokens for r in want]
         print("OK")
     """)
     assert "OK" in out

@@ -62,8 +62,9 @@ def _random_pool(rng, B, Hkv, dh, P, ps, maxp, dtype=jnp.float32):
 @pytest.mark.fast
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("window", [None, 11])
-def test_pallas_paged_decode_matches_ref(rng, dtype, window):
-    B, H, Hkv, dh, ps, P, maxp = 3, 8, 4, 16, 8, 12, 5
+@pytest.mark.parametrize("H,Hkv", [(8, 4), (32, 4)])   # (32, 4): yi-9b's g=8
+def test_pallas_paged_decode_matches_ref(rng, dtype, window, H, Hkv):
+    B, dh, ps, P, maxp = 3, 16, 8, 12, 5
     q = jnp.asarray(rng.normal(size=(B, H, dh)), dtype)
     kpool, vpool, pages, cur = _random_pool(rng, B, Hkv, dh, P, ps, maxp,
                                             dtype)
