@@ -16,12 +16,14 @@ Three faces, one object:
   plain metrics snapshot dict.
 
 Clock-domain rule (mirrors the ``LatencyRecord`` caveat from PR 6):
-every event is stamped by its *caller* on the clock that owns the
-track — a standalone engine stamps its virtual serving clock, a
-cluster's drive engines stamp their per-drive virtual clocks, and the
-coordinator (request spans included) stamps the cluster wall.  The hub
-never reads a clock itself; one timebase per track is the invariant
-the monotonicity tests enforce.
+every event is stamped on the clock that owns its track.  Engine and
+drive tracks (and the ``cluster`` track of ``cluster.tick``) hold
+``span`` phases on the host's wall clock, ``time.perf_counter()``, the
+clock a profiler trace lays against the device's operations; request
+spans and the coordinator stamp the engine's or cluster's virtual
+serving clock, which is what the SLO records are measured on.  Apart
+from ``span``, the hub never reads a clock itself; one timebase per
+track is the invariant the monotonicity tests enforce.
 
 Honesty about cost: the module-level ``NULL_HUB`` is a no-op whose
 every method is ``pass`` behind ``enabled = False`` — instrumentation
@@ -35,10 +37,14 @@ from __future__ import annotations
 import json
 import math
 import threading
+import time
 from collections import deque
 from typing import Any, Dict, List, Optional, Tuple
 
-__all__ = ["NullHub", "NULL_HUB", "TelemetryHub", "DEFAULT_HIST_BUCKETS"]
+from jax.profiler import TraceAnnotation
+
+__all__ = ["NullHub", "NULL_HUB", "TelemetryHub", "DEFAULT_HIST_BUCKETS",
+           "span"]
 
 # seconds-scale latency buckets: 1ms .. 30s, roughly x3 apart
 DEFAULT_HIST_BUCKETS = (0.001, 0.003, 0.01, 0.03, 0.1, 0.3, 1.0, 3.0,
@@ -99,6 +105,49 @@ class NullHub:
 
 
 NULL_HUB = NullHub()
+
+
+class span:
+    """One stretch of engine work, on the profiler's clock and the hub's.
+
+    ``with span(hub, track, name, **stats) as sp:`` wraps the block in a
+    ``jax.profiler.TraceAnnotation`` while a profiler session runs, so
+    the span sits in the trace on the same clock as the device's
+    operations, and, when ``hub.enabled``, records the same interval as
+    a phase on ``track`` stamped on ``time.perf_counter()``.  ``stats``
+    ride on both: the annotation's stats and the phase's attrs.  A stat
+    that costs anything to compute is added inside the block behind the
+    guard, ``if sp.on: sp.stats.update(...)``; with the profiler and the
+    hub both off a span costs two flag tests and no clock read.
+    """
+
+    __slots__ = ("hub", "track", "name", "stats", "on", "_ann", "_t0")
+
+    def __init__(self, hub, track: str, name: str, **stats):
+        self.hub = hub
+        self.track = track
+        self.name = name
+        self.stats = stats
+        self.on = False
+
+    def __enter__(self) -> "span":
+        self._ann = None
+        if TraceAnnotation.is_enabled():
+            self._ann = TraceAnnotation(self.name)
+            self._ann.__enter__()
+        self.on = self._ann is not None or self.hub.enabled
+        if self.hub.enabled:
+            self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        if self.hub.enabled:
+            self.hub.phase(self.track, self.name, self._t0,
+                           time.perf_counter() - self._t0, **self.stats)
+        if self._ann is not None:
+            if self.stats:
+                self._ann.set_metadata(**self.stats)
+            self._ann.__exit__(*exc)
 
 
 class TelemetryHub:

@@ -72,7 +72,7 @@ import threading
 import time
 from collections import deque
 from dataclasses import dataclass, field
-from typing import Deque, Dict, List, Optional, Sequence
+from typing import Deque, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -87,8 +87,9 @@ from repro.core.latency import LatencyRecord
 from repro.core.runtime import (DriveWorker, Heartbeat, HeartbeatWatchdog,
                                 WorkerCommand)
 from repro.core.scheduler import ClusterAdmission
-from repro.core.telemetry import NULL_HUB
-from repro.train.serve_loop import GenResult, ServeEngine, collect_results
+from repro.core.telemetry import NULL_HUB, span
+from repro.train.serve_loop import (GenResult, ServeEngine, TickObservation,
+                                    collect_results)
 
 
 @dataclass
@@ -269,6 +270,8 @@ class ClusterEngine:
         self._inflight: Dict[int, ClusterRequest] = {}
         self._next_rid = 0
         self._finished: List[GenResult] = []
+        # the latest tick's admissions and first tokens, by global rid
+        self.last_tick = TickObservation()
         self._spill_bytes_per_el = jnp.dtype(cfg.dtype).itemsize
         # per-drive virtual clocks for the async parallel-drives model:
         # drives are independent hardware with no tick barrier (the paper's
@@ -784,10 +787,18 @@ class ClusterEngine:
         """One cluster tick.  Serial mode steps every drive in-process
         under the virtual-clock model; ``concurrent=True`` forks the tick
         to the per-drive worker threads and joins on their heartbeats —
-        see ``_step_serial`` / ``_step_concurrent``."""
-        if self.concurrent:
-            return self._step_concurrent()
-        return self._step_serial()
+        see ``_step_serial`` / ``_step_concurrent``.  The tick is the
+        ``cluster.tick`` span, on the wall clock (track ``cluster``); its
+        drive engines' ``serve.*`` spans nest inside it.  ``last_tick``
+        then holds the tick's admissions and first tokens under their
+        global rids, with the drives' wall stamps, and the tick's end."""
+        with span(self.tele, "cluster", "cluster.tick"):
+            if self.concurrent:
+                out = self._step_concurrent()
+            else:
+                out = self._step_serial()
+        self.last_tick.ended_at = time.perf_counter()
+        return out
 
     @property
     def _health(self) -> List[str]:
@@ -799,8 +810,8 @@ class ClusterEngine:
 
     def _absorb_tick(self, d: _Drive, finished: List[GenResult], obs,
                      dt: float, out: List[GenResult],
-                     admit_events: List[int],
-                     first_tok_events: List[int]) -> None:
+                     admit_events: List[Tuple[int, float]],
+                     first_tok_events: List[Tuple[int, float]]) -> None:
         """Fold one drive tick's observations into the shared cluster
         state: virtual clock, pull-scheduler rates, admit/first-token
         event mapping, finished results, and hedge settlement.  The
@@ -812,13 +823,14 @@ class ClusterEngine:
         self.pull.observe(d.drive_id, dt, obs.per_step_items)
         # map engine-local events to global rids BEFORE the finished
         # loop pops rid_map (a request can admit, emit its first token
-        # and finish in the same tick)
-        for local in obs.admitted_rids:
+        # and finish in the same tick); each keeps its drive's wall stamp
+        for local, t in zip(obs.admitted_rids, obs.admitted_at, strict=True):
             if local in d.rid_map:
-                admit_events.append(d.rid_map[local])
-        for local in obs.first_token_rids:
+                admit_events.append((d.rid_map[local], t))
+        for local, t in zip(obs.first_token_rids, obs.first_token_at,
+                            strict=True):
             if local in d.rid_map:
-                first_tok_events.append(d.rid_map[local])
+                first_tok_events.append((d.rid_map[local], t))
         for r in finished:
             if r.rid not in d.rid_map:
                 # abandoned by an earlier fail(), or the losing copy of a
@@ -840,17 +852,23 @@ class ClusterEngine:
             self.stats.completed += 1
 
     def _deliver(self, shed: List[GenResult], out: List[GenResult],
-                 admit_events: List[int],
-                 first_tok_events: List[int]) -> List[GenResult]:
+                 admit_events: List[Tuple[int, float]],
+                 first_tok_events: List[Tuple[int, float]]
+                 ) -> List[GenResult]:
         """Stamp per-request latency at the post-tick cluster clock and
         hand back the tick's results (sheds + completions + failouts)."""
-        for grid in admit_events:
+        self.last_tick = TickObservation(
+            admitted_rids=[g for g, _ in admit_events],
+            admitted_at=[t for _, t in admit_events],
+            first_token_rids=[g for g, _ in first_tok_events],
+            first_token_at=[t for _, t in first_tok_events])
+        for grid, _ in admit_events:
             rec = self.records.get(grid)
             if rec is not None and not math.isfinite(rec.admit_t):
                 rec.admit_t = self.clock
                 if self.tele.enabled:
                     self.tele.request_point(grid, "admit", self.clock)
-        for grid in first_tok_events:
+        for grid, _ in first_tok_events:
             rec = self.records.get(grid)
             if rec is not None and not math.isfinite(rec.first_token_t):
                 rec.first_token_t = self.clock
@@ -930,8 +948,8 @@ class ClusterEngine:
         self._dispatch()
         out: List[GenResult] = []
         dts: List[float] = []
-        admit_events: List[int] = []
-        first_tok_events: List[int] = []
+        admit_events: List[Tuple[int, float]] = []
+        first_tok_events: List[Tuple[int, float]] = []
         n_active = 0
         progressed: set = set()
         for d in self.drives:
@@ -1146,8 +1164,8 @@ class ClusterEngine:
                           for d in self.drives if not d.failed)
         out: List[GenResult] = []
         dts: List[float] = []
-        admit_events: List[int] = []
-        first_tok_events: List[int] = []
+        admit_events: List[Tuple[int, float]] = []
+        first_tok_events: List[Tuple[int, float]] = []
         n_active = 0
         progressed: set = set()
         replied: set = set()

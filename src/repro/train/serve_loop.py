@@ -26,8 +26,8 @@ Mechanics:
     pre-reserves the pages a whole K-block can touch (a host-side lookup
     before the dispatch — growth inside the scan is a pure page-table
     read), and EOS/eviction frees the slot's pages back to the free list
-    in the same tick — peak KV memory and decode reads track live tokens,
-    not ``num_slots * max_len``.  Admission reserves each request's
+    in the same tick — peak KV memory tracks live tokens, not
+    ``num_slots * max_len``.  Admission reserves each request's
     worst-case page count, so a full pool backpressures the queue instead
     of failing mid-decode (``kv_layout="strip"`` keeps the dense per-slot
     reference layout);
@@ -71,7 +71,7 @@ from repro.core.latency import NAN, LatencyRecord, LatencyStats
 from repro.core.scheduler import (PullScheduler, SchedulerState, make_cluster,
                                   optimal_batch_ratio, rebalance_shares,
                                   split_block_service)
-from repro.core.telemetry import NULL_HUB
+from repro.core.telemetry import NULL_HUB, span
 from repro.core.transfer import TransferLedger
 from repro.models import model as M
 
@@ -104,6 +104,9 @@ class ServeStats:
     prefill_s: float = 0.0
     decode_s: float = 0.0
     decode_steps: int = 0        # inner decode steps actually executed
+    # context rows the decode steps attended: per step, the sum over live
+    # slots of the positions each attended (its position + 1)
+    live_kv_tokens: int = 0
     compile_s: float = 0.0       # jit pre-warm time (kept out of decode_s)
     tier_tokens: Dict[str, int] = field(default_factory=dict)
     tier_requests: Dict[str, int] = field(default_factory=dict)
@@ -137,13 +140,16 @@ class ServeStats:
 
     @property
     def kv_bytes_touched(self) -> float:
-        """KV rows the decode kernel actually walked (paged: live pages)."""
+        """KV bytes of the rows the decode steps needed (paged: the rows of
+        the pages in use), not what a kernel read."""
         return self.ledger.kv_bytes
 
     @property
     def kv_reduction(self) -> float:
-        """Fractional KV-traffic reduction vs the dense per-slot strips the
-        baseline decode reads every step (0.0 for the strip layout)."""
+        """1 - the live KV rows the decode steps needed over the dense
+        per-slot strips (0.0 for the strip layout).  It counts what the
+        steps need; the paged kernel on a TPU walks every page of every
+        slot, so it is no reduction of the bytes the kernel reads."""
         if self.baseline.kv_bytes <= 0:
             return 0.0
         return max(1.0 - self.ledger.kv_bytes / self.baseline.kv_bytes, 0.0)
@@ -166,6 +172,7 @@ class ServeStats:
             "prefill_s": self.prefill_s,
             "decode_s": self.decode_s,
             "decode_steps": self.decode_steps,
+            "live_kv_tokens": self.live_kv_tokens,
             "steps_per_s": self.steps_per_s,
             "compile_s": self.compile_s,
             "link_bytes": self.link_bytes,
@@ -201,9 +208,9 @@ class ServeStats:
             f"({m['link_reduction']:.0%} never crossed the link)")
         if m["kv_dense_bytes"] > 0:
             lines.append(
-                f"KV bytes touched: {m['kv_bytes'] / 1e6:.2f} MB vs "
-                f"dense {m['kv_dense_bytes'] / 1e6:.2f} MB "
-                f"({m['kv_reduction']:.0%} fewer KV reads)")
+                f"live KV bytes the steps needed: {m['kv_bytes'] / 1e6:.2f}"
+                f" MB vs dense {m['kv_dense_bytes'] / 1e6:.2f} MB "
+                f"({m['kv_reduction']:.0%} fewer live rows)")
         if self.latency.records:
             lines.append(self.latency.summary())
         if m["shed_requests"]:
@@ -223,6 +230,12 @@ class TickObservation:
     callers timing the whole tick can subtract it — compile happens once
     per process, not once per replica drive, and must not pollute the
     cluster's parallel wall-clock model or the energy integral.
+
+    The ``*_at`` stamps are ``time.perf_counter()`` readings, one per rid
+    of the list beside them: when ``_admit`` gave the request its slot,
+    and when its first token reached the host (the readback of its
+    prefill or last chunk); ``ended_at`` is the end of the tick, when
+    the caller sees those tokens.
     """
     busy_s: float = 0.0          # serving wall time this tick
     compile_s: float = 0.0       # lazy jit/eager-shape compile time
@@ -231,6 +244,9 @@ class TickObservation:
     per_step_items: List[int] = field(default_factory=list)
     admitted_rids: List[int] = field(default_factory=list)
     first_token_rids: List[int] = field(default_factory=list)
+    admitted_at: List[float] = field(default_factory=list)
+    first_token_at: List[float] = field(default_factory=list)
+    ended_at: float = math.nan
 
 
 @dataclass
@@ -405,30 +421,47 @@ class ServeEngine:
             self._prefill_chunk = jit_donor._prefill_chunk
             self._splice_pages = jit_donor._splice_pages
         else:
-            self._decode = jax.jit(
-                lambda p, c, t, pos: M.decode_fn(p, c, t, pos, cfg,
-                                                 self.recipe))
-            self._prefill = jax.jit(
-                lambda p, b: M.prefill_fn(p, b, cfg, self.recipe))
+            # each program is a named function, so its XLA module (and the
+            # profiler's trace of it) carries its name
+            recipe, k_steps = self.recipe, self.k_block
+
+            def decode(p, c, t, pos):
+                return M.decode_fn(p, c, t, pos, cfg, recipe)
+
+            def prefill(p, b):
+                return M.prefill_fn(p, b, cfg, recipe)
+
+            def decode_block(p, c, t, pos, alive, rem):
+                return M.decode_block_fn(p, c, t, pos, alive, rem, cfg,
+                                         recipe, k_steps=k_steps,
+                                         eos_id=eos_id, max_len=max_len)
+
+            def prefill_chunk(p, c, t, qpos, last):
+                return M.prefill_chunk_fn(p, c, t, qpos, last, cfg, recipe)
+
+            self._decode = jax.jit(decode)
+            self._prefill = jax.jit(prefill)
             # Donate the cache pools (and the per-slot decode state) to the
             # fused block so strips/pages update in place instead of being
             # copied every call; CPU has no donation support, so skip the
             # warning noise there.
             donate = (1, 2, 3, 4, 5) if jax.default_backend() != "cpu" else ()
-            self._decode_block = jax.jit(
-                lambda p, c, t, pos, alive, rem: M.decode_block_fn(
-                    p, c, t, pos, alive, rem, cfg, self.recipe,
-                    k_steps=self.k_block, eos_id=eos_id, max_len=max_len),
-                donate_argnums=donate)
+            self._decode_block = jax.jit(decode_block, donate_argnums=donate)
             self._prefill_chunk = jax.jit(
-                lambda p, c, t, qpos, last: M.prefill_chunk_fn(
-                    p, c, t, qpos, last, cfg, self.recipe),
-                donate_argnums=(1,) if donate else ())
+                prefill_chunk, donate_argnums=(1,) if donate else ())
             self._splice_pages = jax.jit(
-                _splice_paged_group, donate_argnums=(0, 1) if donate else ())
+                splice_pages, donate_argnums=(0, 1) if donate else ())
+        # telemetry: engine spans go on ``tele_track`` stamped on the wall
+        # clock (``core.telemetry.span``); request spans stamp the virtual
+        # clock.  The cluster re-points the track per drive and turns
+        # ``tele_requests`` off (drive-local rids would collide with
+        # cluster-global ones — the coordinator owns request spans there)
+        self.tele = telemetry if telemetry is not None else NULL_HUB
+        self.tele_track = "engine"
+        self.tele_requests = True
         # KV layout: "paged" (default) keeps full-attention KV in fixed-size
-        # pages handed out by a free-list allocator — memory and decode
-        # reads track live tokens; "strip" is the dense per-slot reference
+        # pages handed out by a free-list allocator — memory tracks live
+        # tokens; "strip" is the dense per-slot reference
         # layout (one max_len strip per slot).
         self.kv_layout = kv_layout if self._has_paged_layers() else "strip"
         self.page_size = max(page_size, 1)
@@ -497,13 +530,6 @@ class ServeEngine:
         # LatencyRecord timestamps live on it
         self.clock = 0.0
         self.records: Dict[int, LatencyRecord] = {}
-        # telemetry: events stamp this engine's virtual clock on
-        # ``tele_track``; the cluster re-points the track per drive and
-        # turns ``tele_requests`` off (drive-local rids would collide with
-        # cluster-global ones — the coordinator owns request spans there)
-        self.tele = telemetry if telemetry is not None else NULL_HUB
-        self.tele_track = "engine"
-        self.tele_requests = True
         # lazy-compile attribution: the first call at a new (site, shape)
         # key is XLA compile, not serving — its wall time goes to
         # stats.compile_s (and the tick observation) instead of
@@ -519,6 +545,9 @@ class ServeEngine:
             else set()
         self._tick_compile_s = 0.0
         self.last_tick = TickObservation()
+        # when the newest token readback reached the host (perf_counter):
+        # the stamp a first token gets in ``last_tick.first_token_at``
+        self._readback_t = math.nan
         if prewarm:
             self.prewarm()
 
@@ -538,14 +567,16 @@ class ServeEngine:
         group's ``pages`` cache leaf at it.  Mid-prefill slots keep -1 rows
         there, so decode writes route to the scratch page until their last
         chunk is spliced.  A fixed-shape upload: it never compiles."""
-        table = self.page_table.copy()
-        table[[s.index for s in self.slots if s.prefilling]] = -1
-        self._pages_dev = self._put(table)
-        for g, cache in self.caches.items():
-            if isinstance(cache, dict) and "pages" in cache:
-                ng = cache["pages"].shape[0]
-                self.caches[g] = dict(cache, pages=jnp.broadcast_to(
-                    self._pages_dev[None], (ng,) + self._pages_dev.shape))
+        with span(self.tele, self.tele_track, "serve.pages"):
+            table = self.page_table.copy()
+            table[[s.index for s in self.slots if s.prefilling]] = -1
+            self._pages_dev = self._put(table)
+            for g, cache in self.caches.items():
+                if isinstance(cache, dict) and "pages" in cache:
+                    ng = cache["pages"].shape[0]
+                    self.caches[g] = dict(cache, pages=jnp.broadcast_to(
+                        self._pages_dev[None],
+                        (ng,) + self._pages_dev.shape))
 
     def _upload_slot_state(self) -> None:
         """Upload the fused block's per-slot decode state from the host's
@@ -870,15 +901,17 @@ class ServeEngine:
         self._tick_compile_s = 0.0
         tok0, steps0 = self.stats.tokens, self.stats.decode_steps
         busy0 = self.stats.prefill_s + self.stats.decode_s
-        self._shed_expired()
-        self._admit()
-        if self.chunk_prefill is not None:
-            self._chunk_prefill_tick()
-        if any(s.decoding for s in self.slots):
-            if self.k_block > 1:
-                self._decode_block_step()
-            else:
-                self._decode_step()
+        with span(self.tele, self.tele_track, "serve.tick"):
+            self._shed_expired()
+            self._admit()
+            if self.chunk_prefill is not None:
+                self._chunk_prefill_tick()
+            if any(s.decoding for s in self.slots):
+                if self.k_block > 1:
+                    self._decode_block_step()
+                else:
+                    self._decode_step()
+        obs.ended_at = time.perf_counter()
         obs.compile_s = self._tick_compile_s
         obs.tokens = self.stats.tokens - tok0
         obs.steps = self.stats.decode_steps - steps0
@@ -889,9 +922,8 @@ class ServeEngine:
         if self.tele.enabled:
             self.tele.counter(f"{self.tele_track}.ticks")
             self.tele.counter(f"{self.tele_track}.tokens", obs.tokens)
-            self.tele.gauge(f"{self.tele_track}.clock_s", self.clock)
             self.tele.counter_sample(self.tele_track, "queue_depth",
-                                     self.clock, len(self.queue))
+                                     obs.ended_at, len(self.queue))
             if obs.busy_s > 0:
                 self.tele.observe("tick_busy_s", obs.busy_s)
         return self._finished[n_before:]
@@ -915,10 +947,31 @@ class ServeEngine:
     # -- admission + prefill -------------------------------------------------
 
     def _admit(self) -> None:
+        """Give queued requests free slots, then prefill the one-shot ones
+        bucket by bucket (chunked prompts go chunk by chunk, in
+        ``_chunk_prefill_tick``)."""
+        with span(self.tele, self.tele_track, "serve.admit",
+                  queued=len(self.queue)) as sp:
+            oneshot = self._assign_slots()
+            sp.stats["admitted"] = len(self.last_tick.admitted_rids)
+        buckets: Dict[int, List[_Slot]] = {}
+        for slot in oneshot:
+            buckets.setdefault(self._bucket_len(len(slot._prompt)),
+                               []).append(slot)
+        for padded, group in sorted(buckets.items()):
+            with span(self.tele, self.tele_track, "serve.prefill",
+                      padded=padded, rows=len(group),
+                      tokens=sum(len(s._prompt) for s in group)):
+                self._prefill_bucket(group, padded)
+
+    def _assign_slots(self) -> List[_Slot]:
+        """Admission proper: pop queued requests into free slots, as far as
+        the pool can reserve their pages.  Returns the admitted slots whose
+        prompt is prefilled in one shot."""
         free = [s for s in self.slots if not s.active]
         n = min(len(free), len(self.queue))
         if n == 0:
-            return
+            return []
         if self.admission_order == "edf" and len(self.queue) > 1:
             # earliest deadline first; no-deadline requests last.  The sort
             # is stable and ties break on rid, so FIFO order is preserved
@@ -941,7 +994,7 @@ class ServeEngine:
                 fits += 1
             n = fits
             if n == 0:
-                return
+                return []
         tiers = self.admission.tiers_for(n, queued=len(self.queue))
         admitted: List[_Slot] = []
         for slot, tier in zip(free, tiers):
@@ -967,6 +1020,7 @@ class ServeEngine:
                 self.page_table[slot.index, : len(pages)] = pages
             admitted.append(slot)
             self.last_tick.admitted_rids.append(req.rid)
+            self.last_tick.admitted_at.append(time.perf_counter())
             rec = self.records.get(req.rid)
             if rec is not None:
                 rec.admit_t = self.clock
@@ -981,13 +1035,7 @@ class ServeEngine:
             # mid-prefill slots keep their device row -1 (decode writes hit
             # the scratch page) until their last chunk is spliced
             self._upload_pages()
-
-        buckets: Dict[int, List[_Slot]] = {}
-        for slot in oneshot:
-            buckets.setdefault(self._bucket_len(len(slot._prompt)),
-                               []).append(slot)
-        for padded, group in sorted(buckets.items()):
-            self._prefill_bucket(group, padded)
+        return oneshot
 
     def _prefill_bucket(self, group: List[_Slot], padded: int) -> None:
         b = len(group)
@@ -1005,22 +1053,22 @@ class ServeEngine:
         batch = {"tokens": self._put(tokens), "lengths": self._put(lens)}
         nxt, pre_caches = self._prefill(self.params, batch)
         nxt = np.asarray(nxt)
-        t1 = time.perf_counter()
+        t1 = self._readback_t = time.perf_counter()
         # prefill jit is keyed by the bucket length, and so is the paged
         # splice (its index arrays are padded to the bucket's rows); the
         # strip splice runs eager executables keyed by the group size too.
         # Each compiles lazily on first sight, and that wall time is XLA,
         # not serving (see _serving_time)
         dt = self._serving_time(("prefill", padded), t1 - t0)
-        self._splice(pre_caches, [s.index for s in group], lengths, padded)
+        with span(self.tele, self.tele_track, "serve.splice", rows=b,
+                  padded=padded):
+            self._splice(pre_caches, [s.index for s in group], lengths,
+                         padded)
         splice_key = ("splice", padded) if self.kv_layout == "paged" \
             else ("splice", b, padded)
         dt += self._serving_time(splice_key, time.perf_counter() - t1)
         self._account_prefill(sum(lengths))
         self.clock += dt               # first tokens are stamped post-prefill
-        if self.tele.enabled:
-            self.tele.phase(self.tele_track, "prefill", self.clock - dt, dt,
-                            batch=b, padded=padded)
         for i, s in enumerate(group):
             s.prefill_s = dt
             s.cur_token = int(nxt[i])
@@ -1080,21 +1128,21 @@ class ServeEngine:
         prompt = slot._prompt
         c0 = slot.prefill_done_tokens
         real = min(chunk, len(prompt) - c0)
-        tokens = np.zeros((1, chunk), np.int32)
-        tokens[0, :real] = prompt[c0: c0 + real]
-        qpos = np.full((1, chunk), -1, np.int32)
-        qpos[0, :real] = np.arange(c0, c0 + real, dtype=np.int32)
-        view = self._chunk_view(self.page_table[slot.index])
-        t0 = time.perf_counter()
-        nxt, new_view = self._prefill_chunk(
-            self.params, view, self._put(tokens), self._put(qpos),
-            self._put(np.asarray([real - 1], np.int32)))
-        nxt = np.asarray(nxt)
-        dt = self._serving_time(("chunk",), time.perf_counter() - t0)
+        with span(self.tele, self.tele_track, "serve.chunk", tokens=real,
+                  context=c0 + real):
+            tokens = np.zeros((1, chunk), np.int32)
+            tokens[0, :real] = prompt[c0: c0 + real]
+            qpos = np.full((1, chunk), -1, np.int32)
+            qpos[0, :real] = np.arange(c0, c0 + real, dtype=np.int32)
+            view = self._chunk_view(self.page_table[slot.index])
+            t0 = time.perf_counter()
+            nxt, new_view = self._prefill_chunk(
+                self.params, view, self._put(tokens), self._put(qpos),
+                self._put(np.asarray([real - 1], np.int32)))
+            nxt = np.asarray(nxt)
+            self._readback_t = time.perf_counter()
+        dt = self._serving_time(("chunk",), self._readback_t - t0)
         self.clock += dt
-        if self.tele.enabled:
-            self.tele.phase(self.tele_track, "prefill_chunk",
-                            self.clock - dt, dt, rid=slot.rid, tokens=real)
         for g, cache in new_view.items():
             if isinstance(cache, dict) and "kp" in cache:
                 self.caches[g] = dict(self.caches[g], kp=cache["kp"],
@@ -1138,20 +1186,23 @@ class ServeEngine:
                 positions[s.index] = s.pos
         if self.kv_layout == "paged":
             self._grow_pages(1)
-        t0 = time.perf_counter()
-        nxt, self.caches = self._decode(self.params, self.caches,
-                                        self._put(tokens),
-                                        self._put(positions))
-        nxt = np.asarray(nxt)
-        dt = self._serving_time(("decode",), time.perf_counter() - t0)
+        active = [s for s in self.slots if s.decoding]
+        with span(self.tele, self.tele_track, "serve.decode", steps=1,
+                  live_slots=len(active)) as sp:
+            t0 = time.perf_counter()
+            nxt, self.caches = self._decode(self.params, self.caches,
+                                            self._put(tokens),
+                                            self._put(positions))
+            nxt = np.asarray(nxt)
+            self._readback_t = time.perf_counter()
+            if sp.on:
+                sp.stats.update(self._kv_span_stats(
+                    [s.pos for s in active], np.ones((1, len(active)), bool)))
+        dt = self._serving_time(("decode",), self._readback_t - t0)
         self.stats.decode_s += dt
         self.stats.decode_steps += 1
         self.clock += dt
-        if self.tele.enabled:
-            self.tele.phase(self.tele_track, "decode", self.clock - dt, dt,
-                            steps=1)
 
-        active = [s for s in self.slots if s.decoding]
         self._observe_step(active, dt)
         for s in active:
             s.decode_s += dt
@@ -1164,6 +1215,7 @@ class ServeEngine:
         accounting path shared by the K=1 loop and the fused block's
         replay, so stats/rebalance behavior cannot drift between them."""
         self._account_decode(len(live), int(max(s.pos for s in live)) + 1)
+        self.stats.live_kv_tokens += sum(s.pos + 1 for s in live)
         tier_counts: Dict[str, int] = {}
         for s in live:
             tier_counts[s.tier] = tier_counts.get(s.tier, 0) + 1
@@ -1182,49 +1234,65 @@ class ServeEngine:
             # pre-reserve the whole block's pages so growth inside the scan
             # is a pure page-table lookup (reservation makes this infallible)
             self._grow_pages(self.k_block)
-        t0 = time.perf_counter()
-        out = self._decode_block(self.params, self.caches, self._tok_dev,
-                                 self._pos_dev, self._alive_dev,
-                                 self._rem_dev)
-        block, n_steps, tok, pos, alive, rem, caches = out
-        self.caches = caches
-        self._tok_dev, self._pos_dev = tok, pos
-        self._alive_dev, self._rem_dev = alive, rem
-        block = np.asarray(block)                 # ONE readback per block
-        n_steps = int(n_steps)
-        dt = self._serving_time(("decode_block",), time.perf_counter() - t0)
+        active = [s for s in self.slots if s.decoding]
+        with span(self.tele, self.tele_track, "serve.decode_block",
+                  live_slots=len(active)) as sp:
+            t0 = time.perf_counter()
+            out = self._decode_block(self.params, self.caches, self._tok_dev,
+                                     self._pos_dev, self._alive_dev,
+                                     self._rem_dev)
+            block, n_steps, tok, pos, alive, rem, caches = out
+            self.caches = caches
+            self._tok_dev, self._pos_dev = tok, pos
+            self._alive_dev, self._rem_dev = alive, rem
+            block = np.asarray(block)             # ONE readback per block
+            n_steps = int(n_steps)
+            self._readback_t = time.perf_counter()
+            # a slot emitted at step i iff its token row is >= 0 — the live
+            # counts drive the proportional split of the block's wall time
+            emitted = block[:n_steps, [s.index for s in active]] >= 0
+            if sp.on:
+                sp.stats.update(steps=n_steps, **self._kv_span_stats(
+                    [s.pos for s in active], emitted))
+        dt = self._serving_time(("decode_block",), self._readback_t - t0)
         self.stats.decode_s += dt
         self.stats.decode_steps += n_steps
 
-        active = [s for s in self.slots if s.decoding]
-        # a slot emitted at step i iff its token row is >= 0 — the live
-        # counts drive the proportional split of the block's wall time
-        emitted = block[:n_steps, [s.index for s in active]] >= 0
-        self.last_tick.per_step_items = emitted.sum(axis=1).tolist()
-        per_step = split_block_service(dt, self.last_tick.per_step_items)
-        clock_end = self.clock + dt
-        for i in range(n_steps):
-            live = [s for s in active if s.decoding]
-            if not live:
-                break
-            # the clock advances per replayed step so first-token /
-            # completion stamps land at the step's share of the block, not
-            # all at the block boundary
-            self.clock += per_step[i]
-            self._observe_step(live, per_step[i])
-            for s in live:
-                t = int(block[i, s.index])
-                assert t >= 0, "device/host liveness diverged"
-                s.decode_s += per_step[i]
-                s.pos += 1
-                s.cur_token = t
-                self._push_token(s, t)
-        # per_step sums to dt; pin the block end exactly (fp drift, early
-        # break when every slot finished mid-block)
-        self.clock = max(self.clock, clock_end)
-        if self.tele.enabled:
-            self.tele.phase(self.tele_track, "decode_block", clock_end - dt,
-                            dt, steps=n_steps)
+        with span(self.tele, self.tele_track, "serve.replay", steps=n_steps):
+            self.last_tick.per_step_items = emitted.sum(axis=1).tolist()
+            per_step = split_block_service(dt, self.last_tick.per_step_items)
+            clock_end = self.clock + dt
+            for i in range(n_steps):
+                live = [s for s in active if s.decoding]
+                if not live:
+                    break
+                # the clock advances per replayed step so first-token /
+                # completion stamps land at the step's share of the block,
+                # not all at the block boundary
+                self.clock += per_step[i]
+                self._observe_step(live, per_step[i])
+                for s in live:
+                    t = int(block[i, s.index])
+                    assert t >= 0, "device/host liveness diverged"
+                    s.decode_s += per_step[i]
+                    s.pos += 1
+                    s.cur_token = t
+                    self._push_token(s, t)
+            # per_step sums to dt; pin the block end exactly (fp drift,
+            # early break when every slot finished mid-block)
+            self.clock = max(self.clock, clock_end)
+
+    def _kv_span_stats(self, pos0: List[int], emitted: np.ndarray) -> dict:
+        """The decode span's KV counters: ``live_kv_tokens``, the context
+        rows the steps attended (slot s at step i, when it emitted,
+        attends positions ``0 .. pos0[s] + i``), and the pool's pages in
+        use.  The replay counts the same rows into
+        ``stats.live_kv_tokens``, one step at a time."""
+        ctx = np.asarray(pos0, np.int64)[None, :] + 1 \
+            + np.arange(emitted.shape[0])[:, None]
+        return {"live_kv_tokens": int((ctx * emitted).sum()),
+                "kv_pages_in_use": self.pager.num_in_use
+                if self.pager is not None else 0}
 
     def _push_token(self, slot: _Slot, tok: int) -> None:
         """Record a generated token and finish/evict the slot if done."""
@@ -1237,6 +1305,7 @@ class ServeEngine:
             if rec is not None and not math.isfinite(rec.first_token_t):
                 rec.first_token_t = self.clock
             self.last_tick.first_token_rids.append(slot.rid)
+            self.last_tick.first_token_at.append(self._readback_t)
             if self.tele.enabled and self.tele_requests:
                 self.tele.request_point(slot.rid, "first_token", self.clock)
         self.stats.tokens += 1
@@ -1255,15 +1324,16 @@ class ServeEngine:
         (``_reservable_pages`` accounts for the unallocated tail)."""
         grew = False
         ps = self.page_size
-        for s in self.slots:
-            if not s.decoding:
-                continue
-            e = min(steps, max(s.max_new - len(s.out), 1))
-            last = min(s.pos + e - 1, self.max_len - 1)
-            for lp in range(s.pos // ps, last // ps + 1):
-                if self.page_table[s.index, lp] < 0:
-                    self.page_table[s.index, lp] = self.pager.alloc(1)[0]
-                    grew = True
+        with span(self.tele, self.tele_track, "serve.pages"):
+            for s in self.slots:
+                if not s.decoding:
+                    continue
+                e = min(steps, max(s.max_new - len(s.out), 1))
+                last = min(s.pos + e - 1, self.max_len - 1)
+                for lp in range(s.pos // ps, last // ps + 1):
+                    if self.page_table[s.index, lp] < 0:
+                        self.page_table[s.index, lp] = self.pager.alloc(1)[0]
+                        grew = True
         if grew:
             self._upload_pages()
 
@@ -1337,9 +1407,11 @@ class ServeEngine:
         self._account_kv_step()
 
     def _account_kv_step(self) -> None:
-        """KV rows this decode step walks, chosen layout vs the dense
-        baseline (the strip path reads every slot's full strip every step;
-        the paged kernel reads only live pages)."""
+        """Live KV rows this decode step needs, chosen layout vs the dense
+        baseline: the rows of the pages in use (paged) or every slot's
+        full strip (strip).  A count of what the step needs, not of what a
+        kernel reads: on a TPU the paged kernel walks every page of every
+        slot."""
         per_token = self._kv_bytes_per_token()
         if per_token == 0:
             return
@@ -1393,7 +1465,7 @@ def _paged_splice_index(slot_ids: List[int], lengths: List[int],
     return sb, sp, dp, do
 
 
-def _splice_paged_group(kp, vp, k, v, sb, sp, dp, do):
+def splice_pages(kp, vp, k, v, sb, sp, dp, do):
     """Scatter prefill rows ``k/v[:, sb, sp]`` (dense (ng, b, padded, ...))
     into pool rows ``kp/vp[:, dp, do]`` — jitted with the pools donated."""
     return (kp.at[:, dp, do].set(k[:, sb, sp].astype(kp.dtype)),

@@ -264,18 +264,20 @@ def _cluster(cfg, params, ref, **kw):
 
 
 def _assert_track_monotone(events):
-    """Per track, event times are non-decreasing on that track's own
-    clock.  Request spans are exempt: their phase events are emitted at
-    CLOSE time stamped with the OPEN time, so overlapping requests close
-    out of t0 order by design."""
+    """Per track, events are emitted in the order of their stamps on that
+    track's own clock: a point or counter at its time, a phase at its end
+    (a phase is emitted when it closes, so a span that encloses others
+    closes after them).  Request spans are exempt: overlapping requests
+    close out of order by design."""
     last: dict = {}
     for e in events:
         track = e["track"]
         if track in ("requests", "orphans"):
             continue
-        assert e["t"] >= last.get(track, -math.inf) - 1e-9, \
+        t = e["t"] + e.get("dur", 0.0)
+        assert t >= last.get(track, -math.inf) - 1e-9, \
             f"track {track} went backwards: {e}"
-        last[track] = e["t"]
+        last[track] = t
 
 
 def test_engine_tracing_is_token_identical_and_closes_every_span(
@@ -290,7 +292,8 @@ def test_engine_tracing_is_token_identical_and_closes_every_span(
     assert m["counters"].get("telemetry.span_double_close", 0) == 0
     assert m["open_spans"] == 0
     names = {e["name"] for e in hub.events()}
-    assert {"prefill", "decode"} & names
+    assert {"serve.tick", "serve.admit", "serve.prefill",
+            "serve.decode"} <= names
     # first_token precedes every request close
     assert any(e["ev"] == "point" and e["name"] == "first_token"
                for e in hub.events())
@@ -451,12 +454,120 @@ def test_hedge_span_settles_exactly_once_with_waste_attr(cfg, params,
     assert any(e["attrs"]["status"] == "ok" for e in hedge_phases)
 
 
-def test_tracing_on_equals_tracing_off(cfg, params, ref_k1, trace):
-    """The whole-point gate: attaching the hub changes no token."""
+@pytest.mark.parametrize("tracer", ["hub", "profiler"])
+def test_tracing_on_equals_tracing_off(cfg, params, ref_k1, trace, tracer,
+                                       tmp_path):
+    """The whole-point gate: attaching the hub, or running the engine
+    under a profiler session, changes no token."""
     prompts, want = trace
     eng_off = _engine(cfg, params, ref_k1)
     assert eng_off.tele is NULL_HUB and not eng_off.tele.enabled
     off = [r.tokens for r in eng_off.generate(prompts, max_new=6)]
-    eng_on = _engine(cfg, params, ref_k1, telemetry=TelemetryHub())
-    on = [r.tokens for r in eng_on.generate(prompts, max_new=6)]
+    if tracer == "hub":
+        eng_on = _engine(cfg, params, ref_k1, telemetry=TelemetryHub())
+        on = [r.tokens for r in eng_on.generate(prompts, max_new=6)]
+    else:
+        eng_on = _engine(cfg, params, ref_k1)
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            on = [r.tokens for r in eng_on.generate(prompts, max_new=6)]
+        finally:
+            jax.profiler.stop_trace()
+        assert list(tmp_path.rglob("*.xplane.pb"))
     assert on == off == want
+
+
+@pytest.mark.parametrize("system", ["engine", "cluster"])
+def test_engine_track_events_fall_inside_the_call(cfg, params, ref_k1,
+                                                  trace, system):
+    """Engine and drive tracks stamp the wall clock: every event on them
+    lies inside the ``perf_counter`` interval of the ``generate()`` call
+    that made it (the virtual serving clock starts at 0 and would not)."""
+    prompts, want = trace
+    hub = TelemetryHub()
+    if system == "engine":
+        eng, tracks = _engine(cfg, params, ref_k1, telemetry=hub), {"engine"}
+    else:
+        eng = _cluster(cfg, params, ref_k1, n_drives=2, telemetry=hub)
+        tracks = {"drive0", "drive1", "cluster"}
+    n0 = len(hub.events())             # set-up's own page uploads
+    t0 = time.perf_counter()
+    got = [r.tokens for r in eng.generate(prompts, max_new=6)]
+    t1 = time.perf_counter()
+    assert got == want
+    wall = [e for e in hub.events()[n0:] if e["track"] in tracks]
+    assert {e["track"] for e in wall} == tracks
+    assert {e["name"] for e in wall} >= {"serve.tick", "serve.prefill",
+                                          "serve.decode"}
+    for e in wall:
+        assert t0 <= e["t"] <= e["t"] + e.get("dur", 0.0) <= t1, e
+    if system == "cluster":
+        ticks = [e for e in wall if e["name"] == "cluster.tick"]
+        drive_ticks = [e for e in wall if e["name"] == "serve.tick"]
+        assert ticks and drive_ticks
+        # every drive tick nests inside a cluster tick
+        for d in drive_ticks:
+            assert any(c["t"] <= d["t"] and d["t"] + d["dur"]
+                       <= c["t"] + c["dur"] for c in ticks), d
+    _assert_track_monotone(hub.events())
+
+
+def _host_spans(path):
+    """(name, start_ns, end_ns, stats) of the ``serve.*`` events of every
+    host plane in one xplane file."""
+    from jax.profiler import ProfileData
+    out = []
+    for plane in ProfileData.from_file(str(path)).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("serve."):
+                    out.append((e.name, e.start_ns, e.end_ns,
+                                dict(e.stats)))
+    return out
+
+
+def test_profiler_spans_nest_in_the_tick_with_live_kv_counter(cfg, params,
+                                                              tmp_path):
+    """One tick of the reduced model under a CPU profiler session writes
+    ``serve.tick`` with ``serve.admit``, ``serve.prefill`` and
+    ``serve.decode_block`` nested inside it, and the block's
+    ``live_kv_tokens`` stat equals the engine's own count of the rows its
+    replayed steps attended."""
+    eng = ServeEngine(cfg, params, max_len=MAX_LEN, num_slots=2, k_block=4,
+                      bucket_quantum=16, prewarm=True)
+    rng = np.random.default_rng(3)
+    for n in (5, 9):
+        eng.submit(rng.integers(0, cfg.vocab_size, n).tolist(), max_new=6)
+    live0 = eng.stats.live_kv_tokens
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        eng.step()
+    finally:
+        jax.profiler.stop_trace()
+    obs = eng.last_tick
+    assert obs.steps == 4 and len(obs.first_token_rids) == 2
+    # 2 slots x 4 steps, contexts 5+1..5+4 and 9+1..9+4
+    assert eng.stats.live_kv_tokens - live0 == (6 + 7 + 8 + 9) \
+        + (10 + 11 + 12 + 13)
+    (path,) = tmp_path.rglob("*.xplane.pb")
+    spans = _host_spans(path)
+    ticks = [s for s in spans if s[0] == "serve.tick"]
+    assert len(ticks) == 1
+    _, lo, hi, _ = ticks[0]
+    inside = {n for n, s, e, _ in spans if lo <= s <= e <= hi}
+    assert {"serve.admit", "serve.prefill", "serve.decode_block",
+            "serve.replay", "serve.pages"} <= inside
+    (block,) = [st for n, _, _, st in spans if n == "serve.decode_block"]
+    assert block["live_kv_tokens"] == eng.stats.live_kv_tokens - live0
+    assert block["steps"] == 4 and block["live_slots"] == 2
+    assert block["kv_pages_in_use"] == eng.pager.num_in_use
+    (pre,) = [st for n, _, _, st in spans if n == "serve.prefill"]
+    assert pre["rows"] == 2 and pre["tokens"] == 14
+    (adm,) = [st for n, _, _, st in spans if n == "serve.admit"]
+    assert adm["admitted"] == 2 and adm["queued"] == 2
+    # the tick's wall stamps: slot given, first token on the host, tick end
+    assert len(obs.admitted_at) == len(obs.first_token_at) == 2
+    assert max(obs.admitted_at) <= min(obs.first_token_at) \
+        <= max(obs.first_token_at) <= obs.ended_at
