@@ -83,8 +83,9 @@ def paged_decode_partial(q, kpool, vpool, pages, cur_pos, *,
     q: (B,H,dh); kpool/vpool: (P(+scratch), page_size, Hkv, dh); pages:
     (B,maxp) int32 per-slot page tables (-1 = unallocated); cur_pos: (B,)
     per-slot positions.  Unlike ``decode_partial``, the per-slot layout IS
-    the Pallas layout here — the kernel walks the page table via scalar
-    prefetch, so the serve engine's ragged batches get the fused path.
+    the Pallas layout here — the kernel walks each slot's live pages from
+    the scalar-prefetched page table, so the serve engine's ragged batches
+    get the fused path.
     Returns (acc fp32 (B,H,dh), l (B,H), m (B,H)).
     """
     from repro.kernels import paged_decode

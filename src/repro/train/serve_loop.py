@@ -73,6 +73,7 @@ from repro.core.scheduler import (PullScheduler, SchedulerState, make_cluster,
                                   split_block_service)
 from repro.core.telemetry import NULL_HUB, span
 from repro.core.transfer import TransferLedger
+from repro.kernels import paged_decode
 from repro.models import model as M
 
 
@@ -107,6 +108,9 @@ class ServeStats:
     # context rows the decode steps attended: per step, the sum over live
     # slots of the positions each attended (its position + 1)
     live_kv_tokens: int = 0
+    # pages the paged decode kernel's trip counts covered, summed over the
+    # decode steps and the layers that run it (``paged_decode.block_range``)
+    kv_pages_walked: int = 0
     compile_s: float = 0.0       # jit pre-warm time (kept out of decode_s)
     tier_tokens: Dict[str, int] = field(default_factory=dict)
     tier_requests: Dict[str, int] = field(default_factory=dict)
@@ -148,8 +152,8 @@ class ServeStats:
     def kv_reduction(self) -> float:
         """1 - the live KV rows the decode steps needed over the dense
         per-slot strips (0.0 for the strip layout).  It counts what the
-        steps need; the paged kernel on a TPU walks every page of every
-        slot, so it is no reduction of the bytes the kernel reads."""
+        steps need; ``kv_pages_walked`` counts the pages the paged
+        kernel's walk covers."""
         if self.baseline.kv_bytes <= 0:
             return 0.0
         return max(1.0 - self.ledger.kv_bytes / self.baseline.kv_bytes, 0.0)
@@ -173,6 +177,7 @@ class ServeStats:
             "decode_s": self.decode_s,
             "decode_steps": self.decode_steps,
             "live_kv_tokens": self.live_kv_tokens,
+            "kv_pages_walked": self.kv_pages_walked,
             "steps_per_s": self.steps_per_s,
             "compile_s": self.compile_s,
             "link_bytes": self.link_bytes,
@@ -466,6 +471,13 @@ class ServeEngine:
         self.kv_layout = kv_layout if self._has_paged_layers() else "strip"
         self.page_size = max(page_size, 1)
         self._maxp = pages_for(max_len, self.page_size)
+        # the paged kernel's walk: pages a block of it fetches, and the
+        # layers that run it (full-attention GQA layers hold the pools)
+        self._walk_ppb = paged_decode.pages_per_block(
+            self.page_size, cfg.num_kv_heads, cfg.resolved_head_dim,
+            self._maxp)
+        self._walk_layers = sum(k in ("attn", "moe")
+                                for k in cfg.layer_pattern)
         # chunk_prefill: split prompts longer than this into chunk-sized
         # pieces spliced into the paged pool one chunk per engine tick, so a
         # long admission never stalls in-flight decodes for more than one
@@ -1195,9 +1207,13 @@ class ServeEngine:
                                             self._put(positions))
             nxt = np.asarray(nxt)
             self._readback_t = time.perf_counter()
+            emitted = np.ones((1, len(active)), bool)
+            walked = self._kv_pages_walked(active, emitted)
             if sp.on:
                 sp.stats.update(self._kv_span_stats(
-                    [s.pos for s in active], np.ones((1, len(active)), bool)))
+                    [s.pos for s in active], emitted),
+                    kv_pages_walked=walked)
+        self.stats.kv_pages_walked += walked
         dt = self._serving_time(("decode",), self._readback_t - t0)
         self.stats.decode_s += dt
         self.stats.decode_steps += 1
@@ -1251,9 +1267,12 @@ class ServeEngine:
             # a slot emitted at step i iff its token row is >= 0 — the live
             # counts drive the proportional split of the block's wall time
             emitted = block[:n_steps, [s.index for s in active]] >= 0
+            walked = self._kv_pages_walked(active, emitted)
             if sp.on:
-                sp.stats.update(steps=n_steps, **self._kv_span_stats(
-                    [s.pos for s in active], emitted))
+                sp.stats.update(steps=n_steps, kv_pages_walked=walked,
+                                **self._kv_span_stats(
+                                    [s.pos for s in active], emitted))
+        self.stats.kv_pages_walked += walked
         dt = self._serving_time(("decode_block",), self._readback_t - t0)
         self.stats.decode_s += dt
         self.stats.decode_steps += n_steps
@@ -1293,6 +1312,26 @@ class ServeEngine:
         return {"live_kv_tokens": int((ctx * emitted).sum()),
                 "kv_pages_in_use": self.pager.num_in_use
                 if self.pager is not None else 0}
+
+    def _kv_pages_walked(self, active: List[_Slot],
+                         emitted: np.ndarray) -> int:
+        """Pages the paged kernel's trip counts cover over one decode call
+        (``paged_decode.block_range`` on the page table it was given), in
+        all the layers that run it.  At step i a slot that emitted ``n``
+        tokens in the call attends ``pos0 + min(i, n)``: a slot that
+        finished mid-block keeps its last position, and its pages stay in
+        the table until the call returns.  Slots that are not decoding have
+        no pages in the device's table."""
+        if self.kv_layout != "paged" or not active:
+            return 0
+        rows = self.page_table[[s.index for s in active]]
+        pos0 = np.asarray([s.pos for s in active])
+        steps = np.arange(emitted.shape[0])[:, None]
+        cur = pos0 + np.minimum(steps, emitted.sum(axis=0))
+        first, end = paged_decode.block_range(rows, cur, self.page_size,
+                                              self._walk_ppb)
+        return int((end - first).sum()) * self._walk_ppb \
+            * self._walk_layers
 
     def _push_token(self, slot: _Slot, tok: int) -> None:
         """Record a generated token and finish/evict the slot if done."""
@@ -1410,8 +1449,8 @@ class ServeEngine:
         """Live KV rows this decode step needs, chosen layout vs the dense
         baseline: the rows of the pages in use (paged) or every slot's
         full strip (strip).  A count of what the step needs, not of what a
-        kernel reads: on a TPU the paged kernel walks every page of every
-        slot."""
+        kernel reads (``stats.kv_pages_walked`` counts the pages the paged
+        kernel's walk covers)."""
         per_token = self._kv_bytes_per_token()
         if per_token == 0:
             return

@@ -59,15 +59,81 @@ def _random_pool(rng, B, Hkv, dh, P, ps, maxp, dtype=jnp.float32):
         jnp.asarray(cur, jnp.int32)
 
 
-@pytest.mark.fast
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("window", [None, 11])
-@pytest.mark.parametrize("H,Hkv", [(8, 4), (32, 4)])   # (32, 4): yi-9b's g=8
-def test_pallas_paged_decode_matches_ref(rng, dtype, window, H, Hkv):
-    B, dh, ps, P, maxp = 3, 16, 8, 12, 5
+def _pool_with(rng, rows, Hkv, dh, ps, maxp, P, dtype=jnp.float32,
+               order="shuffled"):
+    """Pools and page tables for explicit slots: ``rows`` holds one
+    ``(cur, allocated pages)`` per slot; ``(cur, 0)`` is a slot that owns
+    no page (its row all -1).  Physical pages are handed out in a shuffled
+    (or descending) order, and never page 0."""
+    t = lambda *s: jnp.asarray(rng.normal(size=s), dtype)
+    kpool, vpool = t(P + 1, ps, Hkv, dh), t(P + 1, ps, Hkv, dh)
+    free = rng.permutation(np.arange(1, P)) if order == "shuffled" \
+        else np.arange(P - 1, 0, -1)
+    tables, used = [], 0
+    for _, n_alloc in rows:
+        row = np.full(maxp, -1, np.int32)
+        row[:n_alloc] = free[used: used + n_alloc]
+        used += n_alloc
+        tables.append(row)
+    cur = jnp.asarray([c for c, _ in rows], jnp.int32)
+    return kpool, vpool, jnp.asarray(np.stack(tables)), cur
+
+
+# ragged-edge geometry: dh 16, pages of 8 over 40 logical pages, so the
+# walk takes blocks of 16 pages (128 tokens) and a last block of 8
+EDGE_PS, EDGE_MAXP, EDGE_TPB = 8, 40, 128
+_full = lambda cur: -(-(cur + 1) // EDGE_PS)          # pages holding 0..cur
+EDGE_ROWS = {
+    "page-edge": [(EDGE_PS - 1, 1), (EDGE_PS, 2)],
+    "block-edge": [(EDGE_TPB - 1, _full(EDGE_TPB - 1)),
+                   (EDGE_TPB, _full(EDGE_TPB)),
+                   (EDGE_TPB + 1, _full(EDGE_TPB + 1))],
+    "full-slot": [(EDGE_MAXP * EDGE_PS - 1, EDGE_MAXP), (3, 1)],
+    "inactive-slot": [(200, 0), (50, _full(50)), (0, 0)],
+    "descending-pages": [(300, _full(300)), (100, _full(100))],
+    "past-cur": [(20, 10), (EDGE_TPB + 3, EDGE_MAXP)],
+}
+
+
+def _edge_case(rng, case, H, Hkv, dtype):
+    dh = 16
+    if case == "yi-batch":                     # B=16, g=8 as in yi-9b
+        B = 16
+        kpool, vpool, pages, cur = _random_pool(
+            rng, B, Hkv, dh, B * EDGE_MAXP, EDGE_PS, EDGE_MAXP, dtype)
+    else:
+        rows = EDGE_ROWS[case]
+        B = len(rows)
+        kpool, vpool, pages, cur = _pool_with(
+            rng, rows, Hkv, dh, EDGE_PS, EDGE_MAXP,
+            sum(n for _, n in rows) + 2, dtype,
+            "descending" if case == "descending-pages" else "shuffled")
     q = jnp.asarray(rng.normal(size=(B, H, dh)), dtype)
-    kpool, vpool, pages, cur = _random_pool(rng, B, Hkv, dh, P, ps, maxp,
-                                            dtype)
+    return q, kpool, vpool, pages, cur
+
+
+_RANDOM_CASES = [
+    pytest.param(dtype, window, H, Hkv, "random", marks=pytest.mark.fast,
+                 id=f"{H}-{Hkv}-{window}-{dtype.__name__}")
+    for dtype in (jnp.float32, jnp.bfloat16) for window in (None, 11)
+    for H, Hkv in ((8, 4), (32, 4))]     # (32, 4): yi-9b's g=8
+_EDGE_CASES = [
+    pytest.param(jnp.float32, window, 32, 4, case, id=f"{case}-{window}")
+    for case in (*EDGE_ROWS, "yi-batch") for window in (None, 11)]
+
+
+@pytest.mark.parametrize("dtype,window,H,Hkv,case",
+                         _RANDOM_CASES + _EDGE_CASES)
+def test_pallas_paged_decode_matches_ref(rng, dtype, window, H, Hkv, case):
+    if case == "random":
+        B, dh, ps, P, maxp = 3, 16, 8, 12, 5
+        q = jnp.asarray(rng.normal(size=(B, H, dh)), dtype)
+        kpool, vpool, pages, cur = _random_pool(rng, B, Hkv, dh, P, ps,
+                                                maxp, dtype)
+    else:
+        q, kpool, vpool, pages, cur = _edge_case(rng, case, H, Hkv, dtype)
+        assert paged_decode.pages_per_block(EDGE_PS, Hkv, q.shape[2],
+                                            EDGE_MAXP) * EDGE_PS == EDGE_TPB
     want = paged_decode.paged_decode_partial_ref(q, kpool, vpool, pages, cur,
                                                  window=window)
     got = paged_decode.paged_decode_partial(q, kpool, vpool, pages, cur,
@@ -76,6 +142,36 @@ def test_pallas_paged_decode_matches_ref(rng, dtype, window, H, Hkv):
         else dict(atol=2e-2, rtol=2e-2)
     for a, b in zip(got, want):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), **tol)
+
+
+@pytest.mark.parametrize("window", [None, 11])
+def test_pallas_paged_decode_never_reads_dead_pages(rng, window):
+    """Every pool page outside all slots' live prefixes (pages past
+    ``cur``, unallocated ones — page 0 among them — and the scratch page)
+    is NaN: the kernel's partials stay finite and equal the reference's on
+    the clean pool, so a dead page never enters the math."""
+    rows = [(5, 3), (EDGE_TPB + 2, EDGE_MAXP), (300, _full(300) + 1),
+            (77, 0)]
+    H, Hkv, dh = 32, 4, 16
+    P = sum(n for _, n in rows) + 4
+    kpool, vpool, pages, cur = _pool_with(rng, rows, Hkv, dh, EDGE_PS,
+                                          EDGE_MAXP, P)
+    q = jnp.asarray(rng.normal(size=(len(rows), H, dh)), jnp.float32)
+    live = np.zeros(P + 1, bool)                   # the scratch page is P
+    for row, c in zip(np.asarray(pages), np.asarray(cur)):
+        held = row[: _full(int(c))]
+        live[held[held >= 0]] = True
+    assert not live[0] and not live[P]
+    dead = jnp.asarray(~live)[:, None, None, None]
+    poisoned = [jnp.where(dead, jnp.nan, p) for p in (kpool, vpool)]
+    want = paged_decode.paged_decode_partial_ref(q, kpool, vpool, pages, cur,
+                                                 window=window)
+    got = paged_decode.paged_decode_partial(q, *poisoned, pages, cur,
+                                            window=window, interpret=True)
+    for a, b in zip(got, want):
+        assert np.isfinite(np.asarray(a)).all()
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=5e-6, rtol=5e-6)
 
 
 @pytest.mark.fast
@@ -234,3 +330,65 @@ def test_paged_engine_pallas_interpret_token_identical(cfg, params, rng,
                        k_block=8, chunk_prefill=4)
            .generate(prompts, max_new=3)]
     assert got == want
+
+
+@pytest.mark.parametrize("k_block", [1, 4])
+def test_kv_pages_walked_counts_the_kernels_trip_counts(cfg, params, rng,
+                                                        k_block):
+    """``ServeStats.kv_pages_walked`` and the decode spans' stat equal the
+    pages the paged kernel's trip counts cover — ``block_range`` on the
+    page table and positions each decode call hands the kernel, step by
+    step, times the layers that run it — and stay at most a quarter of the
+    old walk over every page of every slot each step."""
+    from repro.core.telemetry import TelemetryHub
+    hub = TelemetryHub()
+    slots, max_len, ps = 4, 512, 8
+    eng = ServeEngine(cfg, params, max_len=max_len, num_slots=slots,
+                      kv_layout="paged", page_size=ps, k_block=k_block,
+                      telemetry=hub)
+    maxp = max_len // ps
+    ppb = paged_decode.pages_per_block(ps, cfg.num_kv_heads,
+                                       cfg.resolved_head_dim, maxp)
+    layers = sum(k in ("attn", "moe") for k in cfg.layer_pattern)
+
+    def table_of(caches):
+        return next(np.asarray(c["pages"])[0] for c in caches.values()
+                    if isinstance(c, dict) and "pages" in c)
+
+    calls = []                     # (page table, (steps, B) positions)
+    if k_block > 1:
+        block_fn = eng._decode_block
+
+        def spy(p, caches, tok, pos, alive, rem):
+            table, pos0 = table_of(caches), np.asarray(pos)
+            out = block_fn(p, caches, tok, pos, alive, rem)
+            emitted = np.asarray(out[0])[: int(out[1])] >= 0
+            before = np.cumsum(emitted, axis=0) - emitted
+            calls.append((table, pos0 + before))
+            return out
+        eng._decode_block = spy
+    else:
+        step_fn = eng._decode
+
+        def spy(p, caches, tokens, positions):
+            calls.append((table_of(caches), np.asarray(positions)[None]))
+            return step_fn(p, caches, tokens, positions)
+        eng._decode = spy
+
+    # budgets that end mid-block, and a fifth request that refills a slot
+    for n, m in ((5, 6), (17, 3), (30, 9), (9, 5), (24, 7)):
+        eng.submit(rng.integers(0, cfg.vocab_size, n).tolist(), max_new=m)
+    eng.run_until_complete()
+
+    want = 0
+    for table, cur in calls:
+        first, end = paged_decode.block_range(table, cur, ps, ppb)
+        want += int((end - first).sum()) * ppb * layers
+    name = "serve.decode_block" if k_block > 1 else "serve.decode"
+    spans = [e["attrs"]["kv_pages_walked"] for e in hub.events()
+             if e["ev"] == "phase" and e["name"] == name]
+    assert want > 0 and len(spans) == len(calls)
+    assert eng.stats.kv_pages_walked == sum(spans) == want
+    assert eng.stats.metrics()["kv_pages_walked"] == want
+    old_walk = eng.stats.decode_steps * slots * maxp * layers
+    assert 4 * want <= old_walk

@@ -14,6 +14,7 @@ file.
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -54,6 +55,23 @@ def _on(tree, sharding):
     return jax.tree.map(lambda s: _spec(s.shape, s.dtype, sharding), tree)
 
 
+def _pool_relayouts(hlo: str, pool) -> list:
+    """Instructions that write a layer's whole KV pool out again in
+    another order: a transpose of its dims, or a copy into another
+    layout.  The paged kernel reads the pool where it is stored."""
+    out = []
+    for line in hlo.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = bf16\[([\d,]+)\]\{[^}]*\} "
+                     r"([\w-]+)\(", line)
+        if not m:
+            continue
+        shape = tuple(int(d) for d in m.group(2).split(","))
+        if sorted(shape) == sorted(pool) and (
+                shape != tuple(pool) or m.group(3) == "copy"):
+            out.append(m.group(1))
+    return out
+
+
 def test_paged_decode_compiles_at_yi9b_widths(one_chip):
     cfg = one_chip_config()
     H, Hkv, dh = cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim
@@ -68,6 +86,7 @@ def test_paged_decode_compiles_at_yi9b_widths(one_chip):
         _spec((NUM_SLOTS, maxp), jnp.int32, one_chip),
         _spec((NUM_SLOTS,), jnp.int32, one_chip)).compile()
     assert has_kernel(compiled.as_text(), "paged_decode")
+    assert _pool_relayouts(compiled.as_text(), pool) == []
     acc, l, m = compiled.out_info
     assert acc.shape == (NUM_SLOTS, H, dh)
     assert l.shape == m.shape == (NUM_SLOTS, H)
@@ -88,8 +107,9 @@ def test_flash_attention_compiles_at_yi9b_widths(one_chip, sq):
 
 def test_decode_block_fits_one_chip(one_chip, monkeypatch):
     """The chip smoke's fused K-block decode program: the Pallas paged
-    kernel is in it, and its arguments (weights + KV pool + slot state)
-    plus temporaries fit one chip's HBM."""
+    kernel is in it and reads each layer's pool as stored (no per-layer
+    transpose or relayout copy of it), and its arguments (weights + KV
+    pool + slot state) plus temporaries fit one chip's HBM."""
     # kernels/ops.py picks the Pallas path by the default backend, which is
     # the CPU here; the program is compiled for the TPU
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -105,6 +125,9 @@ def test_decode_block_fits_one_chip(one_chip, monkeypatch):
                         _spec((NUM_SLOTS,), jnp.bool_, one_chip),
                         i32()).compile()
     assert has_kernel(compiled.as_text(), "paged_decode")
+    pool = (NUM_SLOTS * (MAX_LEN // PAGE_SIZE) + 1, PAGE_SIZE,
+            cfg.num_kv_heads, cfg.resolved_head_dim)
+    assert _pool_relayouts(compiled.as_text(), pool) == []
     mem = compiled.memory_analysis()
     used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert used < HBM_BYTES, (mem.argument_size_in_bytes,
