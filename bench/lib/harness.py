@@ -12,10 +12,14 @@ request due in the window has finished, or the mix's ``drain_s`` is spent.
 
 What happened in each tick is read from the engine's slots before and
 after it (prompt rows spliced, tokens emitted, per request), which gives
-the live context of every decode step for the FLOP and byte counts.
+the live context of every decode step for the FLOP and byte counts; the
+configuration's architecture counts them (``bench/counts/<arch>.py``).
+A traced run also keeps the engine's own spans and module executions
+(``spans.load``), and ``Run.delta`` reads any counter of ``ServeStats``.
 """
 from __future__ import annotations
 
+import dataclasses
 import gc
 import math
 import os
@@ -81,13 +85,17 @@ class Run:
     window_tokens: float = 0.0
     stats0: Dict[str, float] = field(default_factory=dict)
     stats1: Dict[str, float] = field(default_factory=dict)
+    counts: Optional[object] = None     # the cell's ``Counts``
     # traced slice (``--trace 1``)
     trace: Optional[object] = None
     trace_window: Optional[tuple] = None
+    engine_trace: Optional[object] = None   # ``spans.EngineTrace``
     traced_decode_flops: float = 0.0
     traced_prefill_flops: float = 0.0
-    traced_paged_ideal_s: float = 0.0
-    traced_paged_calls: int = 0
+    # per kernel the count module names: the least time its calls in the
+    # slice could take, and how many calls there were
+    traced_kernel_ideal_s: Dict[str, float] = field(default_factory=dict)
+    traced_kernel_calls: Dict[str, int] = field(default_factory=dict)
 
     def delta(self, key: str) -> float:
         return self.stats1.get(key, 0.0) - self.stats0.get(key, 0.0)
@@ -177,11 +185,14 @@ class Engine:
         return out
 
     def stats(self) -> Dict[str, float]:
-        tot = {"prefill_s": 0.0, "decode_s": 0.0, "decode_steps": 0.0,
-               "tokens": 0.0}
+        """Every int and float field of the drives' ``ServeStats``, summed
+        over the drives."""
+        tot: Dict[str, float] = {}
         for eng in self.engines:
-            for k in tot:
-                tot[k] += float(getattr(eng.stats, k))
+            for f in dataclasses.fields(eng.stats):
+                v = getattr(eng.stats, f.name)
+                if isinstance(v, (int, float)) and not isinstance(v, bool):
+                    tot[f.name] = tot.get(f.name, 0.0) + float(v)
         return tot
 
 
@@ -197,7 +208,7 @@ class Cell:
     spec: Dict
     mix: Dict
     chips: int
-    widths: F.Widths
+    counts: object
     cfg: object
 
     @classmethod
@@ -206,7 +217,8 @@ class Cell:
         cell = S.cell(bench, workload)
         spec = S.config(root, bench, cell["config"])
         return cls(root, bench, workload, spec, S.mix(root, cell["traffic"]),
-                   int(cell["chips"]), F.Widths(spec),
+                   int(cell["chips"]),
+                   S.counts(root, spec["arch"]).Counts(spec),
                    S.system(root, spec["arch"]).model_config(spec))
 
     def requests(self, seed: int, seconds: float, mix=None) -> List[Tracked]:
@@ -233,7 +245,7 @@ def serve_window(cell: Cell, eng: Engine, reqs: List[Tracked],
     from repro.launch.compiles import count_compiles
 
     run = Run(spec=cell.spec, chips=cell.chips, seconds=seconds, peak=peak,
-              reqs=reqs)
+              reqs=reqs, counts=cell.counts)
     by_key: Dict[int, Tracked] = {}
     prof_dir = tempfile.mkdtemp(prefix="bench_trace_") if trace else None
     lag: List[float] = []
@@ -273,7 +285,7 @@ def serve_window(cell: Cell, eng: Engine, reqs: List[Tracked],
                 with _span(tracing, "bench.account"):
                     after = eng.slots()
                     _account(run, eng, by_key, before, after, done, tb - t0,
-                             te - t0, cell.widths, tracing)
+                             te - t0, tracing)
                     before = after
                 if not stats_closed and te >= close:
                     run.stats1, stats_closed = eng.stats(), True
@@ -289,8 +301,7 @@ def serve_window(cell: Cell, eng: Engine, reqs: List[Tracked],
             window_span.__exit__(None, None, None)
             jax.profiler.stop_trace()
         _drain(run, eng, by_key, before, t0,
-               close + float(cell.mix.get("drain_s", 60.0)), cell.widths,
-               stats_closed)
+               close + float(cell.mix.get("drain_s", 60.0)), stats_closed)
     if compiled:
         raise RuntimeError(f"{len(compiled)} programs compiled inside the "
                            f"window: {sorted(set(compiled))[:8]}")
@@ -298,9 +309,11 @@ def serve_window(cell: Cell, eng: Engine, reqs: List[Tracked],
     log(f"generator lateness ms: p50 {pct(lag_ms, 50):.3f} "
         f"p99 {pct(lag_ms, 99):.3f} max {lag_ms.max():.3f}")
     if trace:
+        from bench.lib import spans as SP
         from bench.lib import trace as TR
         files = sorted(Path(prof_dir).rglob("*.xplane.pb"))
         run.trace = TR.load(str(files[-1]))
+        run.engine_trace = SP.load(str(files[-1]))
         shutil.rmtree(prof_dir, ignore_errors=True)
         run.trace_window = run.trace.window()
     return run
@@ -328,11 +341,12 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float,
         dev["busy_s"] = run.device_busy_s()
         dev["window_s"] = run.traced_s()
         lo, hi = run.trace_window
-        log(f"traced {run.traced_s():.3f} s: paged_decode calls counted "
-            f"{run.traced_paged_calls}, in the trace "
-            f"{sum(TR.kernel_count(e, 'paged_decode', lo, hi) for e in run.trace.devices.values())}, "
-            f"kernel {run.kernel_s('paged_decode'):.6f} s, roofline "
-            f"{run.traced_paged_ideal_s:.6f} s")
+        for k, calls in sorted(run.traced_kernel_calls.items()):
+            seen = sum(TR.kernel_count(e, k, lo, hi)
+                       for e in run.trace.devices.values())
+            log(f"traced {run.traced_s():.3f} s: {k} calls counted {calls}, "
+                f"in the trace {seen}, kernel {run.kernel_s(k):.6f} s, "
+                f"roofline {run.traced_kernel_ideal_s[k]:.6f} s")
         result["breakdown"] = {"device_ops": TR.top_ops(run.trace, lo, hi),
                                "idle_gaps": TR.idle_gaps(run.trace, lo, hi)}
     metrics = {}
@@ -384,14 +398,16 @@ class _span:
 
 
 def _account(run: Run, eng: Engine, by_key, before, after, done, tb: float,
-             te: float, w: F.Widths, tracing: bool) -> None:
+             te: float, tracing: bool) -> None:
     """Stamp the tick's events and count its work, drive by drive."""
+    counts = run.counts
     finished = {}
     for res in done:
         finished[res.rid] = res
     emitted = 0
-    decode_flops = prefill_flops = ideal = 0.0
-    calls = 0
+    decode_flops = prefill_flops = 0.0
+    ideal: Dict[str, float] = {}
+    calls: Dict[str, int] = {}
     for d in range(len(eng.engines)):
         b, a = before[d], after[d]
         keys = set(b) | set(a) | {k for k, res in finished.items()
@@ -417,7 +433,7 @@ def _account(run: Run, eng: Engine, by_key, before, after, done, tb: float,
                     and not math.isfinite(r.admit_t):
                 r.admit_t = te
             if pa > pb:
-                prefill_flops += F.prefill_flops(w, pb, pa, pa == plen)
+                prefill_flops += counts.prefill_flops(pb, pa, pa == plen)
             first = nb == 0 and na >= 1
             if first:
                 r.first_t = te
@@ -425,13 +441,14 @@ def _account(run: Run, eng: Engine, by_key, before, after, done, tb: float,
             for i in range(na - nb - int(first)):
                 ctx = plen + m0 + i
                 steps.setdefault(i, []).append(ctx)
-                decode_flops += F.token_flops(w, ctx, logits=True)
+                decode_flops += counts.token_flops(ctx, logits=True)
             emitted += na - nb
         if tracing:
             for ctx in steps.values():
-                fl, by = F.paged_decode_call(w, ctx)
-                ideal += w.layers * F.roofline_s(fl, by, run.peak)
-                calls += w.layers
+                for k, (fl, by, n) in counts.decode_kernels(ctx).items():
+                    ideal[k] = ideal.get(k, 0.0) + \
+                        n * F.roofline_s(fl, by, run.peak)
+                    calls[k] = calls.get(k, 0) + n
     for res in done:
         r = by_key.get(res.rid)
         if r is not None and r.tokens is None:
@@ -439,8 +456,11 @@ def _account(run: Run, eng: Engine, by_key, before, after, done, tb: float,
     if tracing:
         run.traced_decode_flops += decode_flops
         run.traced_prefill_flops += prefill_flops
-        run.traced_paged_ideal_s += ideal
-        run.traced_paged_calls += calls
+        for k, t in ideal.items():
+            run.traced_kernel_ideal_s[k] = \
+                run.traced_kernel_ideal_s.get(k, 0.0) + t
+            run.traced_kernel_calls[k] = \
+                run.traced_kernel_calls.get(k, 0) + calls[k]
     # tokens of the tick that straddles the close count by the share of the
     # tick inside the window
     if tb < run.seconds:
@@ -450,7 +470,7 @@ def _account(run: Run, eng: Engine, by_key, before, after, done, tb: float,
 
 
 def _drain(run: Run, eng: Engine, by_key, before, t0: float, limit: float,
-           w: F.Widths, stats_closed: bool):
+           stats_closed: bool):
     """Serve on, with no new arrivals, until every request due in the window
     has finished or the drain time is spent."""
     while eng.busy() and time.perf_counter() < limit:
@@ -458,7 +478,7 @@ def _drain(run: Run, eng: Engine, by_key, before, t0: float, limit: float,
         done = eng.sys.step()
         te = time.perf_counter() - t0
         after = eng.slots()
-        _account(run, eng, by_key, before, after, done, tb, te, w, False)
+        _account(run, eng, by_key, before, after, done, tb, te, False)
         before = after
         if not stats_closed and te >= run.seconds:
             run.stats1, stats_closed = eng.stats(), True

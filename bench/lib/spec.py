@@ -7,6 +7,9 @@ name ``BENCHMARK.json`` gives it:
   bench/configs/<config>.json     sizes, engine settings, limits
   bench/systems/<arch>.py         the program's model built from a config
   bench/reference/<arch>.py       the plain float32 reference
+  bench/counts/<arch>.py          ``Counts(spec)``: the operations and bytes
+                                  of a token, a prompt range and each
+                                  kernel of a decode step
   bench/traffic/<mix>.json        a traffic mix, read by lib/traffic.py
   bench/metrics/<metric>.py       the reader of one per-layer metric
 """
@@ -64,6 +67,10 @@ def system(root: Path, arch: str):
 
 def reference(root: Path, arch: str):
     return module(Path(root) / "bench" / "reference" / f"{arch}.py")
+
+
+def counts(root: Path, arch: str):
+    return module(Path(root) / "bench" / "counts" / f"{arch}.py")
 
 
 def metric_reader(root: Path, name: str):

@@ -5,6 +5,6 @@ each decoding slot, over the device time of its events."""
 
 def read(run):
     t = run.kernel_s("paged_decode")
-    if t <= 0 or run.traced_paged_calls == 0:
+    if t <= 0 or run.traced_kernel_calls.get("paged_decode", 0) == 0:
         return None
-    return 100.0 * run.traced_paged_ideal_s / t
+    return 100.0 * run.traced_kernel_ideal_s["paged_decode"] / t

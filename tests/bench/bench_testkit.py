@@ -34,6 +34,20 @@ TINY_MIX = {
 PEAK = {"bf16_flops": 1e12, "hbm_bytes_per_s": 1e11, "hbm_bytes": 1e9}
 
 
+def recorded_trace():
+    """A slice of a real trace: the first 1,500 device events of one engine
+    tick of yi-9b.24L serving three requests on a TPU v5e, op names only
+    (read by ``trace.load``), with the host spans trimmed to end with them."""
+    from bench.lib import trace as TR
+    raw = json.loads((REPO / "tests" / "bench" / "data"
+                      / "trace_v5e_tick.json").read_text())
+    tr = TR.Trace()
+    tr.devices = {int(k): [tuple(e) for e in v]
+                  for k, v in raw["devices"].items()}
+    tr.host = [tuple(e) for e in raw["host"]]
+    return tr
+
+
 def tiny_root(tmp: Path, chips: int = 1) -> Path:
     """A root holding ``bench/`` and a BENCHMARK.json with one tiny cell."""
     root = Path(tmp) / "root"

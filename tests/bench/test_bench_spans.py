@@ -77,13 +77,7 @@ def test_recorded_trace_idle_outside_engine_spans_is_its_idle_gaps():
     """On the recorded v5e slice (which predates the engine's spans) the
     new reader puts all idle time outside any engine span, and it agrees
     with the harness's own reading of the same slice."""
-    raw = __import__("json").loads(
-        (K.REPO / "tests" / "bench" / "data" / "trace_v5e_tick.json")
-        .read_text())
-    tr = TR.Trace()
-    tr.devices = {int(k): [tuple(e) for e in v]
-                  for k, v in raw["devices"].items()}
-    tr.host = [tuple(e) for e in raw["host"]]
+    tr = K.recorded_trace()
     lo, hi = tr.window()
     gaps = TR.idle_gaps(tr, lo, hi, n=10 ** 6)
     idle = SP.idle_by_span(tr.devices, [], lo, hi)

@@ -2,7 +2,7 @@
 trace with known answers."""
 from __future__ import annotations
 
-import bench_testkit  # noqa: F401  (puts the repository on the path)
+import bench_testkit as K
 import pytest
 
 from bench.lib import trace as TR
@@ -78,23 +78,8 @@ def test_top_ops_average_over_devices():
     assert top["fusion.1"] == pytest.approx(0.010)       # (15 + 5) / 2
 
 
-def _recorded():
-    """A slice of a real trace: the first 1,500 device events of one engine
-    tick of yi-9b.24L serving three requests on a TPU v5e, op names only
-    (read by ``trace.load``), with the host spans trimmed to end with them."""
-    import json
-    from pathlib import Path
-    raw = json.loads((Path(__file__).parent / "data"
-                      / "trace_v5e_tick.json").read_text())
-    tr = TR.Trace()
-    tr.devices = {int(k): [tuple(e) for e in v]
-                  for k, v in raw["devices"].items()}
-    tr.host = [tuple(e) for e in raw["host"]]
-    return tr
-
-
 def test_recorded_trace_busy_idle_and_kernel_time():
-    tr = _recorded()
+    tr = K.recorded_trace()
     lo, hi = tr.window()
     ev = tr.devices[0]
     busy = TR.busy_ns(ev, lo, hi)
